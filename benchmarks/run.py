@@ -44,7 +44,10 @@ def main(argv=None) -> None:
                          "empty string to disable")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import use_compile_cache
     from repro.obsv import append_ledger, extract_scalars, fingerprint
+
+    use_compile_cache()
     from repro.telemetry import get_telemetry
 
     tel = get_telemetry()

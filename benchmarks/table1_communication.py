@@ -35,6 +35,7 @@ import time
 import jax
 
 from repro.api import ExperimentSpec, problem_dim
+from repro.kernels.mode import resolve_interpret
 
 KERNEL_TIMING_DS = (1408, 16_384, 131_072, 1_000_000)
 
@@ -210,7 +211,7 @@ def run_kernel_timing(ds=KERNEL_TIMING_DS, ratio=0.1, repeats=3, seed=0):
                 "kernel_us": _time(kern),
                 "xla_topk_us": _time(xla),
                 "backend": jax.default_backend(),
-                "interpret_mode": jax.default_backend() != "tpu",
+                "interpret_mode": resolve_interpret(None),
             }
         if tel.enabled:
             tel.event("bench.topk_kernel.row", d=d, k=k, plan=plan,
@@ -267,7 +268,7 @@ def run_agg_roofline(ms=AGG_ROOFLINE_MS, ds=KERNEL_TIMING_DS, ratio=0.1,
     from repro.telemetry import get_telemetry
 
     tel = get_telemetry()
-    interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(None)
     rng = np.random.default_rng(seed)
     rows = []
 

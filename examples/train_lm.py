@@ -23,7 +23,7 @@ def main():
     ap.add_argument("--alpha", type=float, default=0.25)
     args = ap.parse_args()
 
-    _, hist = run_training(
+    _, hist, _ = run_training(
         arch=args.arch,
         preset=args.preset,
         steps=args.steps,

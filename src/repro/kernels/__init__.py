@@ -17,8 +17,9 @@
   ``agg_kernel_plan`` auto-selects the launch.
 * :mod:`rmsnorm` — row-tiled RMSNorm.
 
-Each has a pure-jnp oracle in :mod:`ref` and a jit wrapper in :mod:`ops`;
-kernels run interpret=True off-TPU.
+Each has a pure-jnp oracle in :mod:`ref` and a jit wrapper in :mod:`ops`.
+:func:`mode.resolve_interpret` is the one place that decides whether a
+launch is interpreted: off-TPU it is, on a TPU it lowers through Mosaic.
 """
 from .ops import (
     AGG_BLOCK,

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mode import resolve_interpret
+
 
 def _cubic_kernel(s_ref, g_ref, h_ref, o_ref, *, M, gamma, lr):
     s = s_ref[...].astype(jnp.float32)      # (d,)
@@ -41,8 +43,7 @@ def _cubic_kernel(s_ref, g_ref, h_ref, o_ref, *, M, gamma, lr):
 )
 def cubic_step(s, g, H, *, M=10.0, gamma=1.0, lr=1e-2, interpret=None):
     """One fused Algorithm-2 iteration.  s,g: (d,), H: (d,d)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     d = s.shape[0]
     kernel = functools.partial(_cubic_kernel, M=M, gamma=gamma, lr=lr)
     return pl.pallas_call(

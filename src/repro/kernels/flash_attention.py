@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -94,8 +96,7 @@ def flash_attention(q, k, v, *, causal=True, window=0,
     """q,k,v: (B, H, S, Dh) → (B, H, S, Dh).  GQA is handled by the caller
     (repeat kv heads) — the kernel sees head-major already-matched tensors."""
     B, H, S, Dh = q.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)

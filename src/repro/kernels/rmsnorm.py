@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mode import resolve_interpret
+
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)          # (rows, d)
@@ -26,8 +28,7 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, w, *, eps=1e-6, block_rows=128, interpret=None):
     """x: (N, d); w: (d,)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     N, d = x.shape
     block_rows = min(block_rows, N)
     assert N % block_rows == 0, (N, block_rows)

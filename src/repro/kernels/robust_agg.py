@@ -41,10 +41,12 @@ worker order.  The launch:
    (S = searchsorted of the block edges).  Data-dependent offsets meet
    static BlockSpecs via the **two-view window trick**: the stream is
    passed twice with (1, W) blocks at scalar-prefetched chunk indices
-   q = S[j]//W and q+1, so the concatenated (1, 2W) window always covers
-   [S[j], S[j]+W] ⊇ the run (W ≥ block ≥ occupancy).  In-window entries
-   outside the run are masked by position; the masked one-hot
-   (chunk, block) matmul scatters values to their in-block columns.
+   q = S[j]//W and q+1, so the two consecutive views together always
+   cover [S[j], S[j]+W] ⊇ the run (W ≥ block ≥ occupancy).  The kernel
+   walks each view in 256-lane chunks sliced from its ref; in-window
+   entries outside the run are masked by position, and the masked
+   one-hot (chunk, block) matmul scatters values to their in-block
+   columns.
    Because of step 1's dedup, the matmul is an exact permutation — no
    float summation happens inside the kernel.
 
@@ -57,7 +59,8 @@ Fused distance kernels
 ----------------------
 * **krum** — grid over coordinate blocks; each step accumulates the
   (P, P) pairwise-squared-distance tile (P = m padded to a power of
-  two) from (P, chunk) slabs of its (P, block) tile, revisiting the
+  two) one column per worker — every row of its (P, block) tile
+  against that worker's row — revisiting the
   output block (``@pl.when(j == 0)`` init).  The last grid step finishes
   on-chip: the diagonal takes the registry's +1e30, padding rows/columns
   are masked to +1e30, every column is sorted ascending by a bitonic
@@ -84,8 +87,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import resolve_interpret
+
 # sparse merge: output-block width (multiple of 128 lanes); the largest
-# tile is the (chunk ≤ 512, block) one-hot scatter matmul operand
+# tile is the (256, block) one-hot scatter matmul operand
 AGG_BLOCK = 1024
 # below this d the jnp scatter-add fallback (also payload-shaped — it
 # never builds (m, d)) beats the grid-launch overhead
@@ -127,7 +132,8 @@ def agg_kernel_plan(m: int, d: int, *, k=None, block=None):
                 f"sparse aggregation block size must be a positive multiple "
                 f"of 128 lanes, got {blk}"
             )
-        # VMEM peak: the (512, block) one-hot scatter tile (f32)
+        # VMEM peak: 512 rows of (·, block) 4-byte tiles — the (256,
+        # block) one-hot scatter operand and its column iota
         if 4 * 512 * blk > 14 * 2**20:
             raise ValueError(
                 f"sparse aggregation block={blk} needs "
@@ -180,31 +186,40 @@ def _sorted_stream(vals, idx, d_pad, weights):
 def _sparse_agg_kernel(qw_ref, s_ref, e_ref, v0_ref, v1_ref, i0_ref, i1_ref,
                        out_ref, *, window, block, chunk):
     j = pl.program_id(0)
-    vs = jnp.concatenate([v0_ref[...], v1_ref[...]], axis=1)   # (1, 2W)
-    ix = jnp.concatenate([i0_ref[...], i1_ref[...]], axis=1)
-    pos = qw_ref[j] * window + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 2 * window), 1)
-    live = ((pos >= s_ref[j]) & (pos < e_ref[j])).astype(jnp.float32)
     base = j * block
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, block), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
 
-    def body(c, acc):
-        vc = jax.lax.dynamic_slice(vs, (0, c * chunk), (1, chunk))
-        ic = jax.lax.dynamic_slice(ix, (0, c * chunk), (1, chunk))
-        lc = jax.lax.dynamic_slice(live, (0, c * chunk), (1, chunk))
-        # the dedup pass guarantees ≤ 1 live entry per column: the matmul
-        # is an exact permutation-scatter, never a float reduction
-        onehot = ((ic.reshape(chunk, 1) - base) == cols).astype(
-            jnp.float32) * lc.reshape(chunk, 1)
-        return acc + jax.lax.dot_general(
-            vc, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+    def merge(v_ref, i_ref, start):
+        """Scatter the live entries of one (1, window) view, whose first
+        lane sits at stream position ``start``, chunk by chunk."""
 
+        def body(c, acc):
+            off = pl.multiple_of(c * chunk, chunk)
+            vc = v_ref[:, pl.ds(off, chunk)]
+            ic = i_ref[:, pl.ds(off, chunk)]
+            pos = start + off + lane
+            lc = ((pos >= s_ref[j]) & (pos < e_ref[j])).astype(jnp.float32)
+            # the dedup pass guarantees ≤ 1 live entry per column: the
+            # matmul is an exact permutation-scatter, never a float
+            # reduction
+            onehot = ((ic.reshape(chunk, 1) - base) == cols).astype(
+                jnp.float32) * lc.reshape(chunk, 1)
+            return acc + jax.lax.dot_general(
+                vc, onehot, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+
+        return body
+
+    # the two views are consecutive windows of the stream: walk each
+    # ref in turn (Mosaic slices refs, not loaded values)
+    start = qw_ref[j] * window
+    acc = jax.lax.fori_loop(0, window // chunk, merge(v0_ref, i0_ref, start),
+                            jnp.zeros((1, block), jnp.float32))
     out_ref[...] = jax.lax.fori_loop(
-        0, (2 * window) // chunk, body,
-        jnp.zeros((1, block), jnp.float32))
+        0, window // chunk, merge(v1_ref, i1_ref, start + window), acc)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "block", "interpret"))
@@ -212,8 +227,7 @@ def aggregate_sparse_gridded(vals, idx, d, weights=None, *, block=AGG_BLOCK,
                              interpret=None):
     """Gridded segmented-merge launch: (m, k) payloads → the (d,) f32
     weighted scatter-add aggregate, O(m·k + d) memory, any d."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     m, k = vals.shape
     assert idx.shape == (m, k)
     nb = _round_up(d, block) // block
@@ -221,7 +235,7 @@ def aggregate_sparse_gridded(vals, idx, d, weights=None, *, block=AGG_BLOCK,
     vs, ix = _sorted_stream(vals, idx, d_pad, weights)
     n = vs.shape[0]
     # window ≥ the per-block occupancy bound min(N, block); 256-multiples
-    # keep 2W divisible by the 512-wide scatter chunks
+    # keep each view divisible by the 256-wide scatter chunks
     window = _round_up(min(max(n, 1), block), 256)
     npad = (_round_up(n, window) // window + 2) * window
     vp = jnp.pad(vs, (0, npad - n)).reshape(1, npad)
@@ -230,7 +244,7 @@ def aggregate_sparse_gridded(vals, idx, d, weights=None, *, block=AGG_BLOCK,
     # (evicted duplicates, padding) sort past S[nb] and never merge
     S = jnp.searchsorted(
         ix, jnp.arange(nb + 1, dtype=jnp.int32) * block).astype(jnp.int32)
-    chunk = min(512, 2 * window)
+    chunk = 256                      # divides window (a 256-multiple)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb,),
@@ -303,7 +317,7 @@ def _bitonic_sort_cols(x):
     return x
 
 
-def _krum_kernel(x_ref, d2_ref, score_ref, *, m, k_near, n_blocks, chunk):
+def _krum_kernel(x_ref, d2_ref, score_ref, *, m, k_near, n_blocks):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
@@ -313,14 +327,16 @@ def _krum_kernel(x_ref, d2_ref, score_ref, *, m, k_near, n_blocks, chunk):
 
     x = x_ref[...].astype(jnp.float32)            # (P, block)
     P = x.shape[0]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (P, P), 1)
 
     def body(c, acc):
-        xc = jax.lax.dynamic_slice(x, (0, c * chunk), (P, chunk))
-        diff = xc[:, None, :] - xc[None, :, :]    # (P, P, chunk)
-        return acc + jnp.sum(diff * diff, axis=-1)
+        # column c of the distance tile: every worker against worker c
+        diff = x - x_ref[pl.ds(c, 1), :].astype(jnp.float32)   # (P, block)
+        col = jnp.sum(diff * diff, axis=1, keepdims=True)      # (P, 1)
+        return jnp.where(cols == c, col, acc)
 
     d2_ref[...] += jax.lax.fori_loop(
-        0, x.shape[1] // chunk, body, jnp.zeros((P, P), jnp.float32))
+        0, P, body, jnp.zeros((P, P), jnp.float32))
 
     @pl.when(j == n_blocks - 1)
     def _score():
@@ -343,8 +359,7 @@ def krum_scores_fused(flat, n_byz, *, block=DENSE_BLOCK, interpret=None):
     distances with the score reduction on-chip — only the (m,) scores
     leave the kernel.  k-nearest count matches ``krum_select``:
     k = max(m − n_byz − 2, 1)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     m, d = flat.shape
     plan, P = agg_kernel_plan(m, d, block=block)
     if plan != "fused":
@@ -354,11 +369,10 @@ def krum_scores_fused(flat, n_byz, *, block=DENSE_BLOCK, interpret=None):
         )
     nd = _round_up(d, block) // block
     xp = jnp.pad(flat.astype(jnp.float32), ((0, P - m), (0, nd * block - d)))
-    chunk = 8                                     # (P, P, 8) diff slabs
     _, scores = pl.pallas_call(
         functools.partial(_krum_kernel, m=m,
                           k_near=max(m - int(n_byz) - 2, 1),
-                          n_blocks=nd, chunk=chunk),
+                          n_blocks=nd),
         grid=(nd,),
         in_specs=[pl.BlockSpec((P, block), lambda j: (0, j))],
         out_specs=[
@@ -392,8 +406,7 @@ def sort_workers_fused(updates, *, block=DENSE_BLOCK, interpret=None):
     every real value).  Sorting only permutes, so this equals
     ``jnp.sort(updates, axis=0)`` bit-for-bit — the registry's
     trimmed-mean/median epilogues run unchanged on the output."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     m, d = updates.shape
     plan, P = agg_kernel_plan(m, d, block=block)
     if plan != "fused":

@@ -81,6 +81,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mode import resolve_interpret
+
 # single-tile launch: two (d_pad, d_pad) f32 comparison tiles must sit in
 # ~16 MB VMEM next to the pack buffers ⇒ d ≲ 1.4k; beyond it
 # topk_compress routes to the sharded grid automatically
@@ -163,7 +165,8 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, *, k, d, n_iter):
     rank = rank_of(keep)
 
     kp = vals_ref.shape[1]
-    slot = jax.lax.broadcasted_iota(jnp.float32, (dp, kp), 1)
+    # Mosaic's iota is integer-only: build int32 and convert
+    slot = jax.lax.broadcasted_iota(jnp.int32, (dp, kp), 1).astype(jnp.float32)
     sel = (rank.reshape(dp, 1) == slot).astype(jnp.float32) * keep.reshape(dp, 1)
 
     def gather(row):                                        # (1, dp) @ (dp, kp)
@@ -181,8 +184,7 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, *, k, d, n_iter):
 def topk_compress_tiled(x, k, *, n_iter=64, interpret=None):
     """Single-tile launch (d ≤ :data:`SINGLE_TILE_MAX_D`): one VMEM-resident
     threshold-select + pack pass over the whole vector."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     d = x.shape[-1]
     assert x.ndim == 1 and 1 <= k <= d
     dp, kp = _round_up(d, 128), _round_up(k, 128)
@@ -217,10 +219,13 @@ def _hist_kernel(patt_ref, prefix_ref, hist_ref, *, shift, nbits):
     patt = patt_ref[...]                                    # (1, B) int32
     nbuckets = 1 << nbits
     match = (patt >> (shift + nbits)) == prefix_ref[0, 0]
-    bucket = (patt >> shift) & (nbuckets - 1)
+    # out-of-prefix coordinates take bucket −1, which no column matches
+    # (folding the mask in keeps the (1, B) → (B, 1) relayout on int32:
+    # Mosaic cannot reshape a boolean vector)
+    bucket = jnp.where(match, (patt >> shift) & (nbuckets - 1), -1)
     B = patt.shape[1]
     cols = jax.lax.broadcasted_iota(jnp.int32, (B, nbuckets), 1)
-    onehot = (bucket.reshape(B, 1) == cols) & match.reshape(B, 1)
+    onehot = bucket.reshape(B, 1) == cols
     hist_ref[...] = jnp.sum(onehot.astype(jnp.int32), axis=0, keepdims=True)
 
 
@@ -253,7 +258,7 @@ def _pack_kernel(x_ref, patt_ref, thresh_ref, budget_ref, vals_ref, idx_ref,
     keep = sure + tie * (rank_of(tie) < budget).astype(jnp.float32)
     rank = rank_of(keep)
     W = vals_ref.shape[1]
-    slot = jax.lax.broadcasted_iota(jnp.float32, (B, W), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1).astype(jnp.float32)
     sel = (rank.reshape(B, 1) == slot).astype(jnp.float32) * keep.reshape(B, 1)
 
     def gather(row):                                        # (1, B) @ (B, W)
@@ -268,7 +273,7 @@ def _pack_kernel(x_ref, patt_ref, thresh_ref, budget_ref, vals_ref, idx_ref,
     # Precision.HIGHEST on the dots keeps the MXU from truncating the f32
     # operands to bf16 on real TPUs (positions > 256 and arbitrary values
     # must survive the matmul bit-exactly)
-    lpos = jax.lax.broadcasted_iota(jnp.float32, (1, B), 1)
+    lpos = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1).astype(jnp.float32)
     vals_ref[...] = gather(x)
     idx_ref[...] = jnp.round(gather(lpos)).astype(jnp.int32) + i * B
 
@@ -278,18 +283,22 @@ def topk_compress_sharded(x, k, *, block=DEFAULT_BLOCK, interpret=None):
     """Sharded launch: grid over ``block``-wide coordinate blocks with the
     two-pass global threshold (module docstring, "Sharded launch") —
     model-scale vectors, O(block²) VMEM per grid step, any d."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     d = x.shape[-1]
     assert x.ndim == 1 and 1 <= k <= d
     kernel_plan(d, block)                                   # block sanity
     nb = _round_up(d, block) // block
-    xp = jnp.pad(x.astype(jnp.float32), (0, nb * block - d)).reshape(nb, block)
+    # per-block rows are lifted to (nb, 1, ·) and the grid axis squeezed
+    # out of every block, so each block's last two dims equal the array's
+    # — the TPU's (8, 128) block rule then holds for any nb
+    xp = jnp.pad(x.astype(jnp.float32), (0, nb * block - d)).reshape(
+        nb, 1, block)
     # |x| fp32 bit patterns compare like magnitudes (non-negative floats);
     # padding lanes get the sentinel −1 so no kernel needs a valid mask
     patt = jax.lax.bitcast_convert_type(jnp.abs(xp), jnp.int32)
-    gpos = jnp.arange(nb * block, dtype=jnp.int32).reshape(nb, block)
+    gpos = jnp.arange(nb * block, dtype=jnp.int32).reshape(nb, 1, block)
     patt = jnp.where(gpos < d, patt, -1)
+    row = pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0))
 
     # -- pass 1: radix-select the exact bit pattern p of the k-th |x| ----
     prefix = jnp.zeros((1, 1), jnp.int32)
@@ -299,16 +308,13 @@ def topk_compress_sharded(x, k, *, block=DEFAULT_BLOCK, interpret=None):
         hist = pl.pallas_call(
             functools.partial(_hist_kernel, shift=shift, nbits=nbits),
             grid=(nb,),
-            in_specs=[
-                pl.BlockSpec((1, block), lambda i: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, nbuckets), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((nb, nbuckets), jnp.int32),
+            in_specs=[row, pl.BlockSpec((1, 1), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((None, 1, nbuckets), lambda i: (i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((nb, 1, nbuckets), jnp.int32),
             interpret=interpret,
         )(patt, prefix)
         # host-visible reduction: walk the global histogram from the top
-        g = jnp.sum(hist, axis=0)
+        g = jnp.sum(hist, axis=(0, 1))
         S = jnp.cumsum(g[::-1])[::-1]                # S[q] = count(≥ bucket q)
         in_band = (n_above + S) >= k
         q = jnp.max(jnp.where(in_band,
@@ -318,8 +324,8 @@ def topk_compress_sharded(x, k, *, block=DEFAULT_BLOCK, interpret=None):
     p = prefix                                       # (1, 1): exact pattern
 
     # -- threshold → per-block tie budgets and pack offsets --------------
-    sure_b = jnp.sum(patt > p[0, 0], axis=1)
-    tie_b = jnp.sum(patt == p[0, 0], axis=1)
+    sure_b = jnp.sum(patt > p[0, 0], axis=(1, 2))
+    tie_b = jnp.sum(patt == p[0, 0], axis=(1, 2))
     n_sure = jnp.sum(sure_b)
     tie_before = jnp.cumsum(tie_b) - tie_b           # block order = index order
     budget_b = jnp.clip(k - n_sure - tie_before, 0, tie_b).astype(jnp.int32)
@@ -332,21 +338,21 @@ def topk_compress_sharded(x, k, *, block=DEFAULT_BLOCK, interpret=None):
         functools.partial(_pack_kernel, block=block),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
+            row,
+            row,
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, W), lambda i: (i, 0)),
-            pl.BlockSpec((1, W), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, W), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, W), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, W), jnp.float32),
-            jax.ShapeDtypeStruct((nb, W), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, W), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, W), jnp.int32),
         ],
         interpret=interpret,
-    )(xp, patt, p, budget_b.reshape(nb, 1))
+    )(xp, patt, p, budget_b.reshape(nb, 1, 1))
 
     # compact the blocked slices at their offsets into the (k,) wire
     # arrays (Σ count_b == k exactly, so every slot is written once)

@@ -96,9 +96,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # older jax returns [per-partition dict], newer returns one dict
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
 
     # loop-aware HLO analysis (cost_analysis counts while bodies once —

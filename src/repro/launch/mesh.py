@@ -7,20 +7,28 @@ CPU device, while the dry-run process sees 512 forced host devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the shardings are constraints that GSPMD propagates
+    # through the step, as the sharding rules assume
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
-    """Tiny mesh over however many devices this host actually has —
-    used by the runnable examples/tests on CPU."""
+    """Mesh over the devices of this host: ``(n // model_axis,
+    model_axis)`` on the ``("data", "model")`` axes — the training
+    training loop's worker layout on a one-host TPU slice."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def worker_axes(mesh) -> tuple:

@@ -91,6 +91,39 @@ def skip_reason(cfg: ModelConfig, shape: InputShape) -> str | None:
     return None
 
 
+def worker_shardings(params_shape, mesh, grouped: bool = False):
+    """The mesh runtime's worker layout: ``(w_shard, constrain_worker,
+    constrain_update)`` — the (m, …) worker-tree shardings and the two
+    in-step constraints that keep worker-stacked trees on their data
+    rows and the aggregated update TP-only (see
+    :func:`repro.launch.sharding.worker_tree_specs`)."""
+    w_shard = worker_tree_shardings(params_shape, mesh, grouped=grouped)
+    u_shard = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, P(*s.spec[1:])), w_shard)
+
+    def constrain_worker(tree):
+        return jax.lax.with_sharding_constraint(tree, w_shard)
+
+    def constrain_update(tree):
+        return jax.lax.with_sharding_constraint(tree, u_shard)
+
+    return w_shard, constrain_worker, constrain_update
+
+
+def comm_state_shardings(comm_struct, w_shard, p_shard):
+    """Channel EF state rides like the trees it corrects: the uplink's
+    (m, …) memory as the worker trees, the downlink's as the params;
+    a stateless segment's empty carry stays as it is."""
+
+    def shard(sub, base):
+        if not jax.tree_util.tree_leaves(sub):
+            return sub
+        return jax.tree_util.tree_map(lambda _, sh: sh, sub, base)
+
+    return {"uplink": shard(comm_struct["uplink"], w_shard),
+            "downlink": shard(comm_struct["downlink"], p_shard)}
+
+
 def make_problem(
     cfg: ModelConfig,
     shape: InputShape,
@@ -130,18 +163,8 @@ def make_problem(
         newton = newton or ExperimentSpec(
             problem="external", runtime="mesh", aggregator="norm_trim:0.125"
         ).to_distributed_config()
-        w_shard = worker_tree_shardings(params_shape, mesh, grouped=grouped)
-
-        def constrain_worker(tree):
-            return jax.lax.with_sharding_constraint(tree, w_shard)
-
-        def constrain_update(tree):
-            return jax.lax.with_sharding_constraint(
-                tree,
-                jax.tree_util.tree_map(
-                    lambda s: NamedSharding(mesh, P(*s.spec[1:])), w_shard
-                ),
-            )
+        w_shard, constrain_worker, constrain_update = worker_shardings(
+            params_shape, mesh, grouped=grouped)
 
         stateful = (newton.error_feedback != "none"
                     and (newton.compressor is not None
@@ -156,17 +179,7 @@ def make_problem(
                 constrain_update=constrain_update,
             )
             comm_struct = jax.eval_shape(init_comm_state, params_shape)
-
-            def _comm_shard(sub, stacked):
-                if not jax.tree_util.tree_leaves(sub):
-                    return sub  # stateless segment: empty carry
-                base = w_shard if stacked else p_shard
-                return jax.tree_util.tree_map(lambda _, sh: sh, sub, base)
-
-            cs_shard = {
-                "uplink": _comm_shard(comm_struct["uplink"], True),
-                "downlink": _comm_shard(comm_struct["downlink"], False),
-            }
+            cs_shard = comm_state_shardings(comm_struct, w_shard, p_shard)
 
             def step_fn(params, batch, comm_state):
                 return raw_step(params, batch, jax.random.PRNGKey(0), comm_state)

@@ -1,7 +1,9 @@
 """End-to-end training driver: Byzantine-robust cubic-Newton on an LM.
 
-Runs on whatever devices exist (CPU here, a pod in production — the mesh and
-shardings come from the same code paths the dry-run proves out).
+Runs on the devices of one host.  With one device every array lives on
+it.  With more, the workers go on the ``data`` axis of a
+``(n_devices, 1)`` host mesh, and the parameter, batch and worker-tree
+shardings are the ones :mod:`repro.launch.specs` gives the dry run.
 
 Example (the examples/train_lm.py quickstart uses this):
 
@@ -23,7 +25,8 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding
 
 from ..api import ExperimentSpec, default_aggregator_spec
 from ..checkpoint import save_checkpoint
@@ -38,6 +41,10 @@ from ..data import WorkerBatcher
 from ..models import build_model
 from ..telemetry import (RoundRecord, compile_scope, get_telemetry,
                          rejected_from_keep)
+from .cache import use_compile_cache
+from .mesh import make_host_mesh
+from .sharding import batch_specs, param_shardings
+from .specs import comm_state_shardings, worker_shardings
 
 
 def scale_config(cfg, preset: str):
@@ -86,7 +93,15 @@ def run_training(
     ckpt_dir: str | None = None,
     log_every: int = 10,
     telemetry_dir: str | None = None,
+    sharded: bool | None = None,
 ):
+    """Train for ``steps`` rounds; returns ``(params, losses, kept)`` with
+    the per-step loss and the center's per-step (m,) keep mask (empty
+    for ``robust_sgd``).
+
+    ``sharded`` (default: whenever the host has more than one device)
+    puts the workers on the ``data`` axis of a host mesh; ``False``
+    keeps every array on the default device."""
     tel = get_telemetry()
     if telemetry_dir is not None:
         tel.enable(telemetry_dir)
@@ -95,8 +110,43 @@ def run_training(
     key = jax.random.PRNGKey(seed)
     params = model.init(key)
     n_params = model.param_count(params)
+    if sharded is None:
+        sharded = len(jax.devices()) > 1
+    mesh = make_host_mesh() if sharded else None
+    if mesh is not None and m_workers % mesh.shape["data"]:
+        raise ValueError(
+            f"m_workers={m_workers} must be a multiple of the host's "
+            f"{mesh.shape['data']} devices to put workers on the data axis"
+        )
     print(f"[train] arch={cfg.name} params={n_params/1e6:.1f}M "
-          f"m={m_workers} attack={attack}@{alpha} optimizer={optimizer}")
+          f"m={m_workers} attack={attack}@{alpha} optimizer={optimizer} "
+          f"devices={mesh.size if mesh is not None else 1}")
+
+    constrain_worker = constrain_update = None
+    if mesh is not None:
+        p_shard = param_shardings(params, mesh)
+        w_shard, constrain_worker, constrain_update = worker_shardings(
+            params, mesh)
+        params = jax.device_put(params, p_shard)
+
+        def place_batch(batch):
+            return jax.device_put(batch, jax.tree_util.tree_map(
+                lambda s: NamedSharding(mesh, s), batch_specs(batch, mesh)))
+
+        def pin(raw):
+            """Keep the returned params in their input layout, so every
+            step after the first reuses the first step's executable."""
+            def pinned(params, *rest):
+                out = raw(params, *rest)
+                return (jax.lax.with_sharding_constraint(out[0], p_shard),
+                        *out[1:])
+            return pinned
+    else:
+        def place_batch(batch):
+            return batch
+
+        def pin(raw):
+            return raw
 
     ledger = WireLedger()
     comm_state = None
@@ -122,38 +172,51 @@ def run_training(
             raw_step, init_comm_state = make_stateful_train_step(
                 model.loss_fn, ncfg, m_workers,
                 attack_name=attack, attack_alpha=alpha,
+                constrain_worker=constrain_worker,
+                constrain_update=constrain_update,
             )
             comm_state = init_comm_state(params)
-            step = jax.jit(raw_step, donate_argnums=(3,))
+            if mesh is not None:
+                comm_state = jax.device_put(comm_state, comm_state_shardings(
+                    comm_state, w_shard, p_shard))
+            step = jax.jit(pin(raw_step), donate_argnums=(3,))
         else:
             raw_step = make_train_step(
                 model.loss_fn, ncfg, m_workers,
                 attack_name=attack, attack_alpha=alpha,
+                constrain_worker=constrain_worker,
+                constrain_update=constrain_update,
             )
-            step = jax.jit(raw_step)
+            step = jax.jit(pin(raw_step))
         wire_bits = raw_step.wire_bits(params)  # exact static ints
     else:
-        step = jax.jit(make_robust_sgd_step(model.loss_fn, lr, m_workers, beta=beta))
+        step = jax.jit(pin(make_robust_sgd_step(
+            model.loss_fn, lr, m_workers, beta=beta,
+            constrain_worker=constrain_worker)))
 
     batcher = WorkerBatcher(cfg, m_workers, m_workers * per_worker_batch, seq_len, seed)
     history = []
+    kept = []
     prev_loss = None
     t0 = time.time()
     for it in range(steps):
         key, sub = jax.random.split(key)
+        batch = place_batch(batcher(it))
         # the compile-counter attributes every (re)trace of the mesh step
         # to this scope (host-side contextvar, never traced)
         with compile_scope("mesh.step"):
             if comm_state is not None:
-                params, metrics, comm_state = step(params, batcher(it), sub, comm_state)
+                params, metrics, comm_state = step(params, batch, sub, comm_state)
             else:
-                params, metrics = step(params, batcher(it), sub)
+                params, metrics = step(params, batch, sub)
         if wire_bits is not None:
             ledger.record(uplink=wire_bits["uplink"],
                           downlink=wire_bits["downlink"],
                           rounds=2 if two_round else 1, label="round")
         loss = float(metrics["loss"])
         history.append(loss)
+        if "kept" in metrics:
+            kept.append(np.asarray(metrics["kept"]))
         if tel.enabled:
             tel.round(RoundRecord(
                 step=it, runtime="mesh", loss=loss,
@@ -184,7 +247,7 @@ def run_training(
     if telemetry_dir is not None:
         tel.flush()
         print(f"[train] telemetry → {telemetry_dir}")
-    return params, history
+    return params, history, kept
 
 
 def main(argv=None):
@@ -224,7 +287,8 @@ def main(argv=None):
                          "compile spans, trace.json) into this directory")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    _, hist = run_training(**{k.replace("-", "_"): v for k, v in vars(args).items()})
+    use_compile_cache()
+    _, hist, _ = run_training(**{k.replace("-", "_"): v for k, v in vars(args).items()})
     print(json.dumps({"final_loss": hist[-1], "first_loss": hist[0]}))
 
 

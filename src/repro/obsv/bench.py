@@ -48,16 +48,13 @@ def fingerprint() -> dict:
         ).stdout.strip() or "unknown"
     except (OSError, subprocess.SubprocessError):
         sha = "unknown"
-    try:
-        import jax
-        import jaxlib
-        jax_v, jaxlib_v = jax.__version__, jaxlib.__version__
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        jax_v = jaxlib_v = "unknown"
+    import jax
+    import jaxlib
+
     return {
         "git_sha": sha,
-        "jax": jax_v,
-        "jaxlib": jaxlib_v,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
         "platform": _platform.platform(),
         "python": _platform.python_version(),
         "timestamp_utc": datetime.now(timezone.utc).isoformat(),

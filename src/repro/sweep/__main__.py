@@ -84,7 +84,9 @@ def _add_grid_args(p, with_run=False):
         p.add_argument("--jobs", type=int, default=1, metavar="N",
                        help="run cells on an N-worker process pool "
                             "(spawn-context; merged stores stay byte-"
-                            "identical to --jobs 1)")
+                            "identical to --jobs 1); N > 1 needs "
+                            "JAX_PLATFORMS=cpu, since a chip serves one "
+                            "process")
         p.add_argument("--retry-failed", action="store_true")
         p.add_argument("--retry-truncated", action="store_true",
                        help="re-run cells a previous --budget-s cut short")
@@ -141,6 +143,9 @@ def main(argv=None) -> int:
         return 0
 
     if args.cmd == "run":
+        from ..launch.cache import use_compile_cache
+
+        use_compile_cache()
         plan = _build_plan(args)
         try:
             idx, num = (int(x) for x in args.shard.split("/"))

@@ -25,7 +25,9 @@ and the executor worker id are recorded as *volatile* store fields
 to the serial run's** — the CI ``async-smoke`` job asserts it with
 ``cmp``.  A worker process that dies outright (OOM, segfault) fails
 only its own cell: the parent records a ``status="failed"`` line and
-the pool keeps draining.
+the pool keeps draining.  The pool is for CPU fan-out only: an
+accelerator chip serves one process at a time, so ``jobs > 1`` is
+refused unless ``JAX_PLATFORMS=cpu`` is set in the environment.
 """
 from __future__ import annotations
 
@@ -177,6 +179,15 @@ def run_plan(
     rather than successful builds — resumability makes the distinction
     harmless (the next invocation skips whatever completed).
     """
+    if jobs > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # read from the environment: asking JAX for its backend here
+        # would make this parent take the accelerator before any child
+        # starts, and a chip serves one process at a time
+        raise RuntimeError(
+            f"jobs={jobs} starts {jobs} JAX processes, but an accelerator "
+            f"chip serves one process at a time — run with jobs=1, or set "
+            f"JAX_PLATFORMS=cpu to fan cells out over CPU processes"
+        )
     log = log or (lambda s: None)
     tel = get_telemetry()
     entries = shard_entries(plan.entries, shard_index, num_shards)

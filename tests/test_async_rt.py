@@ -387,6 +387,20 @@ def test_pool_merge_byte_identical_to_serial(pool_stores):
                for r in merged)
 
 
+@pytest.mark.parametrize("platforms", [None, "tpu", "tpu,cpu"])
+def test_pool_refused_unless_cpu_only(tmp_path, monkeypatch, platforms):
+    """A chip serves one process at a time, so jobs > 1 is refused
+    unless the environment pins JAX to the CPU; nothing is built."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    store = ResultStore(str(tmp_path / "s.jsonl"))
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        run_plan(plan_grid(POOL_AXES, POOL_BASE), store, jobs=2)
+    assert not (tmp_path / "s.jsonl").exists()
+
+
 def test_pool_failure_isolation_and_retry(tmp_path):
     plan = plan_grid(POOL_AXES, POOL_BASE)
     bad = plan.entries[0].hash

@@ -458,3 +458,14 @@ def test_rmsnorm_sweep(N, d, dtype, rng):
     np.testing.assert_allclose(
         o.astype(jnp.float32), r.astype(jnp.float32), atol=1e-2 if dtype == jnp.bfloat16 else 1e-5
     )
+
+
+@pytest.mark.parametrize("given,want", [(True, True), (False, False),
+                                        (None, True)])
+def test_resolve_interpret(given, want):
+    """An explicit choice wins; unset, a launch interprets exactly when
+    the backend is not a TPU (the CPU here)."""
+    from repro.kernels.mode import resolve_interpret
+
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret(given) is want
