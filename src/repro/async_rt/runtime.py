@@ -124,7 +124,7 @@ class AsyncCubicNewton(DistributedCubicNewton):
         """
         k_label, k_update, k_comp, _k_grad, _k_down = jax.random.split(key, 5)
         y_used = self._attack_rule.corrupt_labels(k_label, y)
-        s = jax.vmap(
+        s, _ = jax.vmap(
             lambda Xi, yi: self._worker_solve(w, Xi, yi, None)
         )(X, y_used)
         if get_telemetry().enabled:

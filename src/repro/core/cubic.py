@@ -11,7 +11,8 @@ Three solvers are provided:
   LIBSVM regime, d ≤ 300).  Used as the test oracle.
 * :func:`solve_cubic_gd` — the paper's Algorithm 2: plain gradient descent on
   the sub-problem with explicit H, run as a ``lax.while_loop`` on ‖G‖ > τ
-  (iteration-capped so it always terminates under jit).
+  (iteration-capped so it always terminates under jit);
+  :func:`solve_cubic_gd_counted` also returns the loop's trip count.
 * :func:`solve_cubic_hvp` — matrix-free Algorithm 2 for pytree parameters:
   H·s is a Hessian-vector product closure (two backprops), the loop is a
   ``lax.fori_loop`` with a fixed iteration count so the distributed train
@@ -102,6 +103,15 @@ def solve_cubic_gd(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6, max_iters=2000):
             s ← s − ξ G
             G ← g + γ H s + (Mγ²/2) ‖s‖ s
     """
+    return solve_cubic_gd_counted(g, H, M, gamma, lr, tol, max_iters)[0]
+
+
+@partial(jax.jit, static_argnames=("max_iters",))
+def solve_cubic_gd_counted(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6,
+                           max_iters=2000):
+    """:func:`solve_cubic_gd` that also returns the loop's trip count:
+    ``(s, iterations)``, an int32 — the data-dependent factor in the
+    round's device time."""
     if lr is None:
         # 1/(γ(‖H‖+Mγ)) is a safe step for the smooth part of the sub-problem.
         lr = 1.0 / (gamma * (jnp.linalg.norm(H, ord="fro") + M * gamma) + 1e-8)
@@ -116,8 +126,8 @@ def solve_cubic_gd(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6, max_iters=2000):
         G = g + gamma * (H @ s) + 0.5 * M * gamma**2 * jnp.linalg.norm(s) * s
         return it + 1, s, G
 
-    _, s, _ = jax.lax.while_loop(cond, body, (0, jnp.zeros_like(g), g))
-    return s
+    it, s, _ = jax.lax.while_loop(cond, body, (0, jnp.zeros_like(g), g))
+    return s, it
 
 
 # ---------------------------------------------------------------------------

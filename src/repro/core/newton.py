@@ -30,7 +30,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from .cubic import solve_cubic_gd
+from .cubic import solve_cubic_gd_counted
 from ..comm import VectorChannel, WireLedger
 from ..compression import AdaptiveTopK
 from ..telemetry import (
@@ -212,11 +212,12 @@ class DistributedCubicNewton:
 
     # ------------------------------------------------------------------
     def _worker_solve(self, w, X, y, global_g):
-        """One worker: local g, H; solve the cubic sub-problem (Eq. 2)."""
+        """One worker: local g, H; solve the cubic sub-problem (Eq. 2).
+        Returns ``(s, iterations of Algorithm 2)``."""
         cfg = self.config
         g = self._grad_fn(w, X, y) if global_g is None else global_g
         H = self._hess_fn(w, X, y)
-        return solve_cubic_gd(
+        return solve_cubic_gd_counted(
             g,
             H,
             M=cfg.M,
@@ -247,7 +248,7 @@ class DistributedCubicNewton:
             )
             global_g, _ = self.aggregator(per_g)
 
-        s = jax.vmap(
+        s, cubic_iters = jax.vmap(
             lambda Xi, yi: self._worker_solve(w, Xi, yi, global_g)
         )(X, y_used)
 
@@ -312,9 +313,12 @@ class DistributedCubicNewton:
             cfg.eta * v_new, state["downlink"], key=k_down
         )
         w_new = w + delta
+        # the vmapped while_loop runs until its slowest worker is done:
+        # the iterations the device executed this round
         info = {
             "update_norms": update_norms, "keep": keep,
             "uplink_delta": uplink_delta,
+            "cubic_iters": jnp.max(cubic_iters),
         }
         if worker_delta is not None:
             info["worker_delta"] = worker_delta
@@ -433,7 +437,9 @@ class DistributedCubicNewton:
         pooled data).  Returns (w, history dict); the history carries the
         exact integer uplink/downlink wire totals from the ledger plus the
         per-step cumulative total (the bits-to-ε curve's x axis), the
-        per-round measured δ̂, and the adaptive-k trajectory (``None``
+        per-round measured δ̂, Algorithm 2's iterations a round
+        (``cubic_iters``: the maximum over workers, what the vmapped loop
+        runs on the device), and the adaptive-k trajectory (``None``
         entries on non-adaptive wires) — so sweep stores can pivot on
         them.
 
@@ -446,6 +452,18 @@ class DistributedCubicNewton:
         any) defines the saddle-escape flag: the round whose loss first
         drops below it is the escape round (telemetry round records +
         ``hist["saddle_escape_step"]``)."""
+        with get_telemetry().span("newton.solve"):
+            return self._solve(w0, X, y, n_steps, key, eval_fn, grad_tol,
+                               full_data, deadline, saddle_value)
+
+    def _solve(self, w0, X, y, n_steps, key, eval_fn, grad_tol, full_data,
+               deadline, saddle_value):
+        """The body of :meth:`run`.  Each round is a ``newton.round`` span
+        with three children: ``.step`` dispatches the jitted round,
+        ``.wait`` is the host blocked on it (the round's one pull),
+        ``.pooled`` the pooled gradient norm and loss, compiles included
+        (attributed to ``compile_scope("newton.pooled")``).  The rest of
+        the round span is the host loop's own work."""
         import time as _time
 
         key = key if key is not None else jax.random.PRNGKey(0)
@@ -459,7 +477,7 @@ class DistributedCubicNewton:
         ledger = self.ledger
         ledger.reset()
         hist = {"loss": [], "grad_norm": [], "eval": [], "rounds": 0,
-                "bits_cumulative": [], "uplink_delta": [],
+                "bits_cumulative": [], "uplink_delta": [], "cubic_iters": [],
                 "k_trajectory": [], "saddle_escape_step": None,
                 "truncated": False}
         tel = get_telemetry()
@@ -477,55 +495,58 @@ class DistributedCubicNewton:
                 if tel.enabled:
                     tel.event("newton.truncated", step=t)
                 break
-            key, sub = jax.random.split(key)
-            k_live = self._uplink_k()      # the k this round transmits at
-            w, v, state, info = self.step(w, X, y, sub, v, state)
-            # re-read every step: adaptive compressors move k between steps
-            bps = self.bits_per_step()
-            ledger.record(uplink=bps["uplink"], downlink=bps["downlink"],
-                          rounds=self.rounds_per_step, label="round")
-            hist["bits_cumulative"].append(ledger.total_bits)
-            delta_hat = float(info["uplink_delta"])
-            hist["uplink_delta"].append(delta_hat)
-            hist["k_trajectory"].append(k_live)
-            gn = float(jnp.linalg.norm(gradf(w, Xf, yf)))
-            loss = float(lossf(w, Xf, yf))
-            hist["loss"].append(loss)
-            hist["grad_norm"].append(gn)
-            if eval_fn is not None:
-                hist["eval"].append(float(eval_fn(w)))
-            hit_tol = grad_tol is not None and gn <= grad_tol
-            k_changed = False
-            if not hit_tol:
-                k_changed = self._maybe_adapt(gn, measured_delta=delta_hat)
-            escaped = (saddle_value is not None
-                       and hist["saddle_escape_step"] is None
-                       and loss < saddle_value)
-            if escaped:
-                hist["saddle_escape_step"] = t
-            if tel.enabled:
-                center_bytes = self.center_bytes_per_round()
-                tel.round(RoundRecord(
-                    step=t, runtime=self.runtime_label, loss=loss,
-                    grad_norm=gn,
-                    model_decrease=(None if prev_loss is None
-                                    else prev_loss - loss),
-                    uplink_delta=delta_hat, k=k_live, k_changed=k_changed,
-                    saddle_escape=escaped,
-                    rejected=rejected_from_keep(info["keep"]),
-                    attack=self.attack.name, alpha=self.attack.alpha,
-                    wire_uplink_bits=bps["uplink"],
-                    wire_downlink_bits=bps["downlink"],
-                    center_bytes=center_bytes,
-                    agg_kernel=self._agg_kernel_label(),
-                    **self._worker_round_fields(info, X.shape[0], bps,
-                                                tracker),
-                ), name="newton.round")
-                # the O(m·k)-vs-O(m·d) claim, measured per round
-                tel.gauge("newton.center_bytes", center_bytes, step=t,
-                          agg_kernel=self._agg_kernel_label(),
-                          aggregator=self.aggregator.name)
-                prev_loss = loss
+            with tel.span("newton.round", step=t):
+                key, sub = jax.random.split(key)
+                k_live = self._uplink_k()      # the k this round transmits at
+                with tel.span("newton.round.step"):
+                    w, v, state, info = self.step(w, X, y, sub, v, state)
+                # re-read every step: adaptive compressors move k between steps
+                bps = self.bits_per_step()
+                ledger.record(uplink=bps["uplink"], downlink=bps["downlink"],
+                              rounds=self.rounds_per_step, label="round")
+                hist["bits_cumulative"].append(ledger.total_bits)
+                with tel.span("newton.round.wait"):
+                    delta_hat, cubic_iters = jax.device_get(
+                        (info["uplink_delta"], info["cubic_iters"]))
+                delta_hat = float(delta_hat)
+                hist["uplink_delta"].append(delta_hat)
+                hist["cubic_iters"].append(int(cubic_iters))
+                hist["k_trajectory"].append(k_live)
+                with tel.span("newton.round.pooled"), \
+                        compile_scope("newton.pooled"):
+                    gn = float(jnp.linalg.norm(gradf(w, Xf, yf)))
+                    loss = float(lossf(w, Xf, yf))
+                hist["loss"].append(loss)
+                hist["grad_norm"].append(gn)
+                if eval_fn is not None:
+                    hist["eval"].append(float(eval_fn(w)))
+                hit_tol = grad_tol is not None and gn <= grad_tol
+                k_changed = False
+                if not hit_tol:
+                    k_changed = self._maybe_adapt(gn, measured_delta=delta_hat)
+                escaped = (saddle_value is not None
+                           and hist["saddle_escape_step"] is None
+                           and loss < saddle_value)
+                if escaped:
+                    hist["saddle_escape_step"] = t
+                if tel.enabled:
+                    tel.round(RoundRecord(
+                        step=t, runtime=self.runtime_label, loss=loss,
+                        grad_norm=gn,
+                        model_decrease=(None if prev_loss is None
+                                        else prev_loss - loss),
+                        uplink_delta=delta_hat, k=k_live,
+                        k_changed=k_changed, saddle_escape=escaped,
+                        rejected=rejected_from_keep(info["keep"]),
+                        attack=self.attack.name, alpha=self.attack.alpha,
+                        wire_uplink_bits=bps["uplink"],
+                        wire_downlink_bits=bps["downlink"],
+                        center_bytes=self.center_bytes_per_round(),
+                        agg_kernel=self._agg_kernel_label(),
+                        **self._worker_round_fields(info, X.shape[0], bps,
+                                                    tracker),
+                    ), name="newton.round")
+                    prev_loss = loss
             if hit_tol:
                 break
         hist.update(ledger.snapshot())

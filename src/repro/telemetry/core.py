@@ -4,9 +4,11 @@ Design contract (the HLO-identity test pins it):
 
 * **Disabled is the default and costs nothing.**  Every emit method
   checks one boolean and returns; ``span()`` hands back a shared no-op
-  context manager; :func:`device_event` stages *nothing* into a trace —
-  the lowered HLO with telemetry disabled is bit-identical to a build
-  without the telemetry integration at all.
+  context manager (a bare profiler annotation while a JAX profiler
+  session records, so spans reach its trace); :func:`device_event`
+  stages *nothing* into a trace — the lowered HLO with telemetry
+  disabled is bit-identical to a build without the telemetry
+  integration at all.
 * **Instrumentation is host-side.**  Both runtimes already surface
   every per-round quantity as concrete metrics on the host, so round
   records, wire events, spans, and compile events are plain Python on
@@ -53,6 +55,20 @@ class _NoopSpan:
 
 
 _NOOP_SPAN = _NoopSpan()
+
+_annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported at the first span: the
+    package itself imports no JAX, and no profiler session can be active
+    before JAX is."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 def _percentile(sorted_vals, q: float):
@@ -271,10 +287,12 @@ class Telemetry:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+        parent = stack[-1] if stack else None
         stack.append(name)
         status = "ok"
         try:
-            yield self
+            with _trace_annotation()(name, **attrs):
+                yield self
         except BaseException:
             status = "error"
             raise
@@ -284,6 +302,8 @@ class Telemetry:
             ev = self._base("span", name)
             ev["ts"] = round(t0, 6)
             ev["dur_s"] = round(dur, 6)
+            if parent is not None:
+                ev["parent"] = parent
             if attrs or status != "ok":
                 ev["args"] = {**{k: str(v) for k, v in attrs.items()},
                               **({"status": status}
@@ -294,10 +314,18 @@ class Telemetry:
 
     def span(self, name: str, **attrs):
         """``with tel.span("sweep.cell", hash=h): …`` — a timed scope
-        emitted to both sinks.  Free when disabled."""
-        if not self._enabled:
-            return _NOOP_SPAN
-        return self._span_cm(name, attrs)
+        emitted to both sinks, its JSONL event naming the enclosing span
+        (``parent``).  Whenever a JAX profiler session is active the span
+        is also a ``jax.profiler.TraceAnnotation``, so it lands in the
+        profiler's trace on the device ops' clock — with telemetry
+        disabled too, as a bare annotation.  Disabled with no profiler
+        session: the shared no-op, free."""
+        if self._enabled:
+            return self._span_cm(name, attrs)
+        annotation = _trace_annotation()
+        if annotation.is_enabled():
+            return annotation(name, **attrs)
+        return _NOOP_SPAN
 
     def current_span(self) -> Optional[str]:
         stack = getattr(self._local, "stack", None)

@@ -16,7 +16,8 @@ Base keys (every event):
 
 Per-kind required keys (on top of the base):
 
-* ``span``    — ``dur_s`` (float ≥ 0); optional ``args`` dict
+* ``span``    — ``dur_s`` (float ≥ 0); optional ``args`` dict and
+  ``parent`` (the name of the enclosing span on the same thread)
 * ``counter`` — ``value`` (number), the post-increment running total
 * ``gauge``   — ``value`` (number)
 * ``hist``    — ``value`` (number), one observation
@@ -97,6 +98,7 @@ EVENT_SCHEMA = {
         "total_bits": {"type": "integer", "minimum": 0},
         "event": {"type": "string"},
         "args": {"type": "object"},
+        "parent": {"type": "string", "minLength": 1},
         "center_bytes": {"type": "integer", "minimum": 0},
         "agg_kernel": {"enum": ["sparse", "fused", "dense"]},
         "seq": {"type": "integer", "minimum": 0},

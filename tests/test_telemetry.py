@@ -3,8 +3,10 @@
 Pins the subsystem's three contracts:
 
 * **disabled is free** — every emit early-returns, ``span()`` is a shared
-  no-op, and :func:`device_event` stages nothing: the lowered HLO with
-  telemetry disabled is bit-identical to code without the call;
+  no-op (a bare profiler annotation while a JAX profiler session records,
+  which leaves the lowered round unchanged), and :func:`device_event`
+  stages nothing: the lowered HLO with telemetry disabled is
+  bit-identical to code without the call;
 * **enabled is exact** — events are schema-valid JSONL, the Chrome trace
   parses, per-transmit wire events sum to the WireLedger's integer
   totals, and round records mirror the histories both runtimes return;
@@ -120,6 +122,23 @@ def test_device_event_hlo_identity():
     t_on.disable()
 
 
+def test_disabled_span_is_a_bare_annotation_while_profiling(tmp_path):
+    """Telemetry off: the span enters the JAX profiler's trace while a
+    session records, and is the shared no-op before and after."""
+    from jax.profiler import TraceAnnotation
+
+    t = Telemetry()
+    assert not TraceAnnotation.is_enabled()
+    assert t.span("s") is _NOOP_SPAN
+    with jax.profiler.trace(str(tmp_path)):
+        span = t.span("s", step=3)
+        assert isinstance(span, TraceAnnotation)
+        with span:
+            pass
+    assert t.span("s") is _NOOP_SPAN
+    assert list(tmp_path.rglob("*.xplane.pb"))
+
+
 # -------------------------------------------------------------- enabled
 def test_emits_are_schema_valid_and_trace_parses(tel):
     tel.event("e", foo="bar")
@@ -140,6 +159,19 @@ def test_emits_are_schema_valid_and_trace_parses(tel):
         assert validate_event(ev) == [], ev
     assert check_wire_exactness(events) == []
     assert check_chrome_trace(os.path.join(tel.out_dir, "trace.json")) == []
+
+
+def test_enabled_span_names_its_parent_and_reaches_the_profiler(tel, tmp_path):
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with tel.span("outer", label="x"):
+            with tel.span("inner"):
+                pass
+    spans = {e["name"]: e for e in _events(tel) if e["kind"] == "span"}
+    assert spans["inner"]["parent"] == "outer"
+    assert "parent" not in spans["outer"]
+    assert validate_event(spans["inner"]) == []
+    names = {n for _, _, n in _host_events(tmp_path / "prof")}
+    assert {"outer", "inner"} <= names
 
 
 def test_histogram_percentiles():
@@ -217,6 +249,95 @@ def test_saddle_escape_flag_and_step():
     esc = hist["saddle_escape_step"]
     below = [i for i, l in enumerate(hist["loss"]) if l < sv]
     assert esc == (below[0] if below else None)
+
+
+# ------------------------------------ run-loop spans on the profiler
+def _host_events(log_dir):
+    """``(start_ns, end_ns, name)`` of every host event in the one
+    ``.xplane.pb`` under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = log_dir.rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+
+
+def _profiled(log_dir, fn):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        return fn()
+
+
+def test_paper_run_spans_nest_on_the_profiler_host_plane(tmp_path, monkeypatch):
+    """With telemetry off, a profiled run puts newton.solve ⊃ newton.round
+    ⊃ {step, wait, pooled} on the host plane, one round span a round."""
+    from repro.telemetry import core
+
+    monkeypatch.setattr(core, "_GLOBAL", Telemetry())
+    exp = ExperimentSpec(**PAPER_KW).build()
+    exp.run(1)                                   # compiles outside the trace
+    _, hist = _profiled(tmp_path, lambda: exp.run(3))
+    spans = {}
+    for s, e, name in _host_events(tmp_path):
+        if name.startswith("newton."):
+            spans.setdefault(name, []).append((s, e))
+
+    def inside(child, parents):
+        return all(any(ps <= s and e <= pe for ps, pe in spans[parents])
+                   for s, e in spans[child])
+
+    assert len(spans["newton.solve"]) == 1
+    assert len(spans["newton.round"]) == hist["rounds"] == 3
+    assert inside("newton.round", "newton.solve")
+    for child in ("newton.round.step", "newton.round.wait",
+                  "newton.round.pooled"):
+        assert len(spans[child]) == 3
+        assert inside(child, "newton.round")
+
+
+def test_cubic_iters_count_algorithm_2():
+    """hist["cubic_iters"] is the most iterations any worker's Algorithm 2
+    ran in the round, counted here by a plain Python loop."""
+    spec = ExperimentSpec(problem="synthetic-logistic:120:12", m_workers=4,
+                          aggregator="mean")
+    exp = spec.build()
+    _, hist = exp.run(1)
+    cfg, prob = exp.algo.config, exp.problem
+    loss = exp.algo.loss_fn
+    counts = []
+    for X, y in zip(prob.X_workers, prob.y_workers):
+        g = jax.grad(loss)(prob.w0, X, y)
+        H = jax.hessian(loss)(prob.w0, X, y)
+        lr = 1.0 / (cfg.gamma * (jnp.linalg.norm(H, ord="fro")
+                                 + cfg.M * cfg.gamma) + 1e-8)
+        s, G, it = jnp.zeros_like(g), g, 0
+        while float(jnp.linalg.norm(G)) > cfg.solver_tol \
+                and it < cfg.solver_iters:
+            s = s - lr * G
+            G = g + cfg.gamma * (H @ s) \
+                + 0.5 * cfg.M * cfg.gamma**2 * jnp.linalg.norm(s) * s
+            it += 1
+        counts.append(it)
+    assert 1 < max(counts) < cfg.solver_iters
+    assert hist["cubic_iters"] == [max(counts)]
+
+
+def test_round_hlo_identical_with_profiler_open_and_closed(tmp_path):
+    """The spans are host-side: the round a profiled run lowers is the
+    round an unprofiled run lowers."""
+    def lowered():
+        algo = ExperimentSpec(**PAPER_KW).build().algo
+        algo._ensure_channels(12, 4)
+        w = jnp.zeros(12)
+        X, y = jnp.ones((4, 30, 12)), jnp.ones((4, 30))
+        return algo._step.lower(w, w, algo.init_comm_state(), X, y,
+                                jax.random.PRNGKey(0)).as_text()
+
+    closed = lowered()
+    assert _profiled(tmp_path, lowered) == closed
 
 
 # -------------------------------------------- observation ≠ perturbation
@@ -350,8 +471,7 @@ def test_telemetry_report_aggregates(tel, tmp_path):
 
 def test_round_records_carry_center_path_fields(tel):
     """Round records carry the v2 center-path fields (center_bytes +
-    agg_kernel) at the current schema version, and the
-    newton.center_bytes gauge mirrors them — sparse and dense paths."""
+    agg_kernel) at the current schema version — sparse path."""
     from repro.telemetry.schema import SCHEMA_VERSION
 
     spec = ExperimentSpec(problem="synthetic-logistic:120:12", m_workers=4,
@@ -367,10 +487,6 @@ def test_round_records_carry_center_path_fields(tel):
     for r in rounds:
         assert r["agg_kernel"] == "sparse"
         assert r["center_bytes"] == m * k * 8 + 4 * d
-    gauges = [e for e in events if e["kind"] == "gauge"
-              and e["name"] == "newton.center_bytes"]
-    assert len(gauges) == len(rounds)
-    assert all(g["value"] == rounds[0]["center_bytes"] for g in gauges)
     assert validate_stream(json.dumps(e) for e in events) == []
 
 
