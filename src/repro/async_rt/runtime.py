@@ -180,8 +180,6 @@ class AsyncCubicNewton(DistributedCubicNewton):
         if full_data is None:
             full_data = (X.reshape(-1, X.shape[-1]), y.reshape(-1))
         Xf, yf = full_data
-        gradf = jax.jit(jax.grad(self.loss_fn))
-        lossf = jax.jit(self.loss_fn)
         m = X.shape[0]
         self._ensure_channels(w0.shape[0], m)
         if self._use_sparse_center:
@@ -209,7 +207,7 @@ class AsyncCubicNewton(DistributedCubicNewton):
                 "cohort_size": [], "n_arrivals": [], "queue_depth": [],
                 "staleness_mean": []}
         tel = get_telemetry()
-        prev_loss = float(lossf(w0, Xf, yf)) if tel.enabled else None
+        prev_loss = self._pooled_eval(w0, Xf, yf)[0] if tel.enabled else None
         tracker = SuspicionTracker(m) if tel.enabled else None
         w = w0
         v = jnp.zeros_like(w0)
@@ -318,8 +316,7 @@ class AsyncCubicNewton(DistributedCubicNewton):
             hist["staleness_mean"].append(
                 sum(ages) / len(ages) if ages else None
             )
-            gn = float(jnp.linalg.norm(gradf(w, Xf, yf)))
-            loss = float(lossf(w, Xf, yf))
+            loss, gn = self._pooled_eval(w, Xf, yf)
             hist["loss"].append(loss)
             hist["grad_norm"].append(gn)
             if eval_fn is not None:

@@ -130,6 +130,10 @@ class DistributedCubicNewton:
         self._attack_rule = resolve_attack(attack)
         self._grad_fn = jax.grad(loss_fn)
         self._hess_fn = jax.hessian(loss_fn)
+        # built once per runtime, so only the first solve compiles it;
+        # the pooled data are arguments (closed over, they would become
+        # constants of the executable)
+        self._pooled = jax.jit(self._pooled_impl)
         self.rounds_per_step = 2 if config.exact_gradient else 1
         self.ledger = WireLedger()
         # channels need (d, m); built once at the first step
@@ -209,6 +213,18 @@ class DistributedCubicNewton:
             "grad": (self.grad_uplink.init_state()
                      if self.grad_uplink is not None else jnp.zeros((0,))),
         }
+
+    def _pooled_impl(self, w, Xf, yf):
+        """``(loss, ‖∇loss‖)`` on the pooled data, one forward pass."""
+        loss, g = jax.value_and_grad(self.loss_fn)(w, Xf, yf)
+        return loss, jnp.linalg.norm(g)
+
+    def _pooled_eval(self, w, Xf, yf):
+        """The pooled loss and gradient norm as host floats: one
+        dispatch of the jitted pooled program and one pull."""
+        with compile_scope("newton.pooled"):
+            loss, gn = jax.device_get(self._pooled(w, Xf, yf))
+        return float(loss), float(gn)
 
     # ------------------------------------------------------------------
     def _worker_solve(self, w, X, y, global_g):
@@ -461,8 +477,9 @@ class DistributedCubicNewton:
         """The body of :meth:`run`.  Each round is a ``newton.round`` span
         with three children: ``.step`` dispatches the jitted round,
         ``.wait`` is the host blocked on it (the round's one pull),
-        ``.pooled`` the pooled gradient norm and loss, compiles included
-        (attributed to ``compile_scope("newton.pooled")``).  The rest of
+        ``.pooled`` the pooled loss and gradient norm, one program that
+        compiles in the runtime's first solve only (attributed to
+        ``compile_scope("newton.pooled")``).  The rest of
         the round span is the host loop's own work."""
         import time as _time
 
@@ -470,8 +487,6 @@ class DistributedCubicNewton:
         if full_data is None:
             full_data = (X.reshape(-1, X.shape[-1]), y.reshape(-1))
         Xf, yf = full_data
-        gradf = jax.jit(jax.grad(self.loss_fn))
-        lossf = jax.jit(self.loss_fn)
 
         self._ensure_channels(w0.shape[0], X.shape[0])
         ledger = self.ledger
@@ -483,7 +498,7 @@ class DistributedCubicNewton:
         tel = get_telemetry()
         # f(w0) anchors the first round's model decrease; only computed
         # when someone is listening (one extra loss eval)
-        prev_loss = float(lossf(w0, Xf, yf)) if tel.enabled else None
+        prev_loss = self._pooled_eval(w0, Xf, yf)[0] if tel.enabled else None
         tracker = SuspicionTracker(X.shape[0]) if tel.enabled else None
         w = w0
         v = jnp.zeros_like(w0)
@@ -512,10 +527,8 @@ class DistributedCubicNewton:
                 hist["uplink_delta"].append(delta_hat)
                 hist["cubic_iters"].append(int(cubic_iters))
                 hist["k_trajectory"].append(k_live)
-                with tel.span("newton.round.pooled"), \
-                        compile_scope("newton.pooled"):
-                    gn = float(jnp.linalg.norm(gradf(w, Xf, yf)))
-                    loss = float(lossf(w, Xf, yf))
+                with tel.span("newton.round.pooled"):
+                    loss, gn = self._pooled_eval(w, Xf, yf)
                 hist["loss"].append(loss)
                 hist["grad_norm"].append(gn)
                 if eval_fn is not None:

@@ -14,8 +14,9 @@ Attribution: the monitoring callback carries no function identity, so
 runtimes label their compile sites with :func:`compile_scope` — a
 contextvar the listener reads while the (synchronous) compile runs.
 ``DistributedCubicNewton.step`` runs under ``compile_scope
-("newton.step")``, the pooled loss and gradient that its ``run()``
-jits anew in every call under ``"newton.pooled"``, the mesh facade
+("newton.step")``, the pooled loss-and-gradient-norm program that
+``run()`` evaluates every round (compiled once per runtime) under
+``"newton.pooled"``, the mesh facade
 under ``"mesh.step"``, so
 ``counter.backend_compiles("newton.step")`` is exactly "how many times
 did the paper runtime's step recompile" — the number the regression

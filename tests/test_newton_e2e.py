@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.api import ExperimentSpec, problems
 from repro.core import AttackConfig, DistributedCubicNewton, NewtonConfig
 from repro.data import make_classification, make_regression, shard_to_workers
 
@@ -118,3 +119,35 @@ def test_momentum_variant(logistic_data):
     _, h_m = mom.run(jnp.zeros(20), Xm, ym, 10)
     assert h_m["loss"][-1] <= h_b["loss"][-1] + 1e-3
     assert all(jnp.isfinite(jnp.asarray(h_m["loss"])))
+
+
+@pytest.mark.parametrize("problem", ["synthetic-logistic:120:12",
+                                     "synthetic-regression:120:12"])
+def test_pooled_program_matches_separate_passes(problem):
+    """The fused pooled program gives the loss and gradient norm of two
+    separate passes, and a seeded run reads the same through either."""
+    exp = ExperimentSpec(problem=problem, m_workers=4,
+                         aggregator="norm_trim:0.3", attack="gaussian",
+                         alpha=0.25).build()
+    algo, prob = exp.algo, exp.problem
+    assert algo.loss_fn in (problems.logistic_loss,
+                            problems.robust_regression_loss)
+    lossf = jax.jit(algo.loss_fn)
+    gradf = jax.jit(jax.grad(algo.loss_fn))
+    X = prob.X_workers.reshape(-1, prob.dim)
+    y = prob.y_workers.reshape(-1)
+    w = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (prob.dim,))
+    loss, gn = algo._pooled(w, X, y)
+    assert float(loss) == pytest.approx(float(lossf(w, X, y)), rel=1e-6)
+    assert float(gn) == pytest.approx(
+        float(jnp.linalg.norm(gradf(w, X, y))), rel=1e-6)
+
+    key = jax.random.PRNGKey(7)
+    _, fused = exp.run(4, key=key)
+    algo._pooled = lambda w, X, y: (lossf(w, X, y),
+                                    jnp.linalg.norm(gradf(w, X, y)))
+    _, separate = exp.run(4, key=key)
+    assert fused["rounds"] == separate["rounds"] == 4
+    assert fused["total_bits"] == separate["total_bits"]
+    for name in ("loss", "grad_norm"):
+        assert fused[name] == pytest.approx(separate[name], rel=1e-6)

@@ -14,7 +14,7 @@ Pins the subsystem's three contracts:
   on produces a byte-identical merged store to one with telemetry off,
   and the compile-counter pins the expected number of XLA compiles
   (recompile hygiene: 3 for the adaptive-k ladder's 3 distinct k,
-  exactly 1 per sweep cell).
+  exactly 1 per sweep cell, 1 pooled program per runtime).
 """
 import json
 import os
@@ -425,6 +425,27 @@ def test_compile_pin_sweep_one_compile_per_cell(tmp_path):
     with cc:
         _run_sweep(str(tmp_path / "s.jsonl"), n_cells=2)
     assert cc.backend_compiles("newton.step") == 2
+
+
+@pytest.mark.parametrize("runtime_kw, round_scopes", [
+    ({}, ("newton.step",)),
+    (dict(runtime="async", participation=0.5, staleness=1),
+     ("async.compute", "async.downlink")),
+], ids=["paper", "async"])
+def test_compile_pin_pooled_once_per_runtime(runtime_kw, round_scopes):
+    """The pooled loss-and-norm program compiles exactly once in a
+    runtime's first run; a second run with another key compiles nothing
+    under ``newton.pooled`` or the runtime's round programs."""
+    exp = ExperimentSpec(**runtime_kw, **PAPER_KW).build()
+    first = CompileCounter()
+    with first:
+        exp.run(3, key=jax.random.PRNGKey(1))
+    assert first.backend_compiles("newton.pooled") == 1
+    again = CompileCounter()
+    with again:
+        exp.run(3, key=jax.random.PRNGKey(2))
+    for scope in ("newton.pooled",) + round_scopes:
+        assert again.backend_compiles(scope) == 0, scope
 
 
 # --------------------------------------------------------- CLI / report
