@@ -427,7 +427,7 @@ class Experiment:
 
                 self.algo = DistributedCubicNewton(
                     self.problem.loss_fn, self.config,
-                    spec.to_attack_config()
+                    spec.to_attack_config(), eval_fn=self.problem.eval_fn,
                 )
             self.step = None
         else:

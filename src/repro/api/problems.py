@@ -67,7 +67,8 @@ def factor_loss(w, X, y):
 
 
 def accuracy(w, X, y):
-    return float(((X @ w > 0) == (y > 0.5)).mean())
+    """Share of rows classified right, as a jnp scalar (traceable)."""
+    return ((X @ w > 0) == (y > 0.5)).mean()
 
 
 # ---------------------------------------------------------------- catalog
@@ -93,15 +94,18 @@ class Problem:
 
     @property
     def eval_fn(self) -> Optional[Callable]:
-        """Test accuracy for classification problems, else None."""
+        """Test accuracy for classification problems, else None: a
+        ``functools.partial`` of :func:`accuracy` over the test split, so
+        the runtime can stage it on the device with the split as
+        arguments."""
         if self.kind == "logistic" and self.X_test is not None:
-            return lambda w: accuracy(w, self.X_test, self.y_test)
+            return functools.partial(accuracy, X=self.X_test, y=self.y_test)
         return None
 
     def accuracy(self, w) -> float:
         X = self.X_test if self.X_test is not None else self.X_full
         y = self.y_test if self.y_test is not None else self.y_full
-        return accuracy(w, X, y)
+        return float(accuracy(w, X, y))
 
 
 def _ints(spec: str, arg: str, defaults: tuple) -> tuple:

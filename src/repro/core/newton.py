@@ -25,10 +25,13 @@ architectures lives in :mod:`repro.core.distributed`.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import time
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .cubic import solve_cubic_gd_counted
 from ..comm import VectorChannel, WireLedger
@@ -42,6 +45,44 @@ from ..telemetry import (
     record_retrace,
     rejected_from_keep,
 )
+
+# Rounds one device stretch may run: ``run()`` makes one dispatch and one
+# pull a stretch, so a solve of up to this many rounds makes one of each.
+STRETCH_ROUNDS = 32
+
+
+def _float32_at_most(x: float) -> np.float32:
+    """The largest float32 ≤ ``x``: a float32 norm ``n`` passes
+    ``n <= it`` exactly when ``float(n) <= x``, the host loop's test.
+    (The nearest float32 may lie above ``x``, and pass a norm that the
+    host loop would not.)"""
+    t = np.float32(x)
+    return np.nextafter(t, np.float32(-np.inf)) if float(t) > x else t
+
+
+def _eval_parts(eval_fn):
+    """``(function, data)`` of an evaluation, staged as ``function(*args,
+    w, **kwargs)`` with ``data = (args, kwargs)``: a ``functools.partial``
+    hands its bound arrays to the stretch as arguments (closed over, they
+    would become constants of the executable, and a new closure a solve
+    would compile anew); any other callable is staged as it is."""
+    if isinstance(eval_fn, functools.partial):
+        return eval_fn.func, (eval_fn.args, eval_fn.keywords)
+    return eval_fn, ((), {})
+
+
+def _row_names(with_eval: bool) -> list:
+    """The numbers a device stretch records each round, in its rows."""
+    return ["loss", "grad_norm", "uplink_delta", "cubic_iters"] + (
+        ["eval"] if with_eval else [])
+
+
+def _empty_history() -> dict:
+    """The history a paper-runtime solve fills, either path."""
+    return {"loss": [], "grad_norm": [], "eval": [], "rounds": 0,
+            "bits_cumulative": [], "uplink_delta": [], "cubic_iters": [],
+            "k_trajectory": [], "saddle_escape_step": None,
+            "truncated": False, "device_rounds": 0}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,6 +141,9 @@ class DistributedCubicNewton:
     ``runtime_label`` names the runtime in emitted round records;
     subclasses (the async runtime) override it.
 
+    ``eval_fn`` is the evaluation ``step()`` stages with its round, so
+    that a ``step()`` compiles the program ``run(eval_fn=eval_fn)`` runs.
+
     Channels (and their compressors / error-feedback wrappers) are
     resolved ONCE, lazily at the first step for the observed ``(d, m)``
     — never inside a trace.  ``self.ledger`` accumulates exact integer
@@ -113,6 +157,7 @@ class DistributedCubicNewton:
         loss_fn: Callable,
         config: NewtonConfig = NewtonConfig(),
         attack: AttackConfig = AttackConfig(),
+        eval_fn: Optional[Callable] = None,
     ):
         # registries resolve ONCE here, never inside a trace (the api
         # import is lazy purely to keep the package import graph acyclic)
@@ -134,6 +179,9 @@ class DistributedCubicNewton:
         # the pooled data are arguments (closed over, they would become
         # constants of the executable)
         self._pooled = jax.jit(self._pooled_impl)
+        self.eval_fn = eval_fn
+        self._traces: dict = {}    # evaluation function -> stages on device
+        self._consts: dict = {}    # (dtype, value) -> device scalar
         self.rounds_per_step = 2 if config.exact_gradient else 1
         self.ledger = WireLedger()
         # channels need (d, m); built once at the first step
@@ -158,6 +206,9 @@ class DistributedCubicNewton:
                    if isinstance(ch.compressor, AdaptiveTopK)},
             )
         self._step = jax.jit(self._step_impl)
+        self._stretch = jax.jit(self._stretch_impl,
+                                static_argnames="eval_call")
+        self._carry0 = None    # (w0's shape and dtype, v0, state0)
 
     def _ensure_channels(self, d: int, m: int):
         if self._dims == (d, m):
@@ -213,6 +264,25 @@ class DistributedCubicNewton:
             "grad": (self.grad_uplink.init_state()
                      if self.grad_uplink is not None else jnp.zeros((0,))),
         }
+
+    def _const(self, value):
+        """``value`` as a device scalar (int32, float32 or bool), placed
+        once per runtime: a stretch's bound, ε and key flag cross to the
+        device once, not in every dispatch."""
+        value = np.asarray(value, np.int32 if type(value) is int else None)
+        k = (value.dtype.str, value.tobytes())
+        if k not in self._consts:
+            self._consts[k] = jax.device_put(value)
+        return self._consts[k]
+
+    def _fresh_carry(self, w0):
+        """``(v, state)`` a device solve starts from: zeros, made once per
+        channel set and shared by every solve (arrays are immutable), so
+        a solve dispatches no eager op of its own."""
+        aval = (jnp.shape(w0), jnp.result_type(w0))
+        if self._carry0 is None or self._carry0[0] != aval:
+            self._carry0 = (aval, jnp.zeros_like(w0), self.init_comm_state())
+        return self._carry0[1:]
 
     def _pooled_impl(self, w, Xf, yf):
         """``(loss, ‖∇loss‖)`` on the pooled data, one forward pass."""
@@ -340,11 +410,89 @@ class DistributedCubicNewton:
             info["worker_delta"] = worker_delta
         return w_new, v_new, new_state, info
 
+    def _stretch_impl(self, w, v, state, X, y, full, eval_data, key, bound,
+                      tol, key_is_round_key, *, eval_call):
+        """Up to ``bound`` (≤ :data:`STRETCH_ROUNDS`) rounds in one
+        ``lax.while_loop``, each as the host loop runs it: split the key,
+        the round, the pooled ``(loss, ‖∇loss‖)`` at the new iterate, the
+        evaluation.  It stops after the first round whose norm is
+        ≤ ``tol``.  Returns the state after the last round, the key to go
+        on from, the stretch's *record* and the last round's ``info``.
+        The record is float32 ``(len(names) + 1, STRETCH_ROUNDS)``: one
+        row a number (:func:`_row_names`), a column a round, and a last
+        row that starts with the rounds run and whether ``tol`` was met.
+        ``key_is_round_key`` makes ``key`` itself the first round's key
+        (``step()``).  The pooled data are ``full``, or the workers' rows
+        when it is None."""
+        Xf, yf = ((X.reshape(-1, X.shape[-1]), y.reshape(-1))
+                  if full is None else full)
+        names = _row_names(eval_call is not None)
+        rows0 = {n: jnp.zeros(STRETCH_ROUNDS, jnp.int32 if n == "cubic_iters"
+                              else jnp.float32) for n in names}
+        info0 = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(self._step_impl, w, v, state, X, y, key)[3])
+
+        def body(c):
+            w, v, state, key, t, _, rows, _ = c
+            next_key, sub = jax.random.split(key)
+            sub = jnp.where(key_is_round_key & (t == 0), key, sub)
+            w, v, state, info = self._step_impl(w, v, state, X, y, sub)
+            # the barrier keeps the evaluations from fusing with the round,
+            # so they read as the host loop's own programs, to the last bit
+            w_, Xf_, yf_, eval_data_ = jax.lax.optimization_barrier(
+                (w, Xf, yf, eval_data))
+            loss, gn = self._pooled_impl(w_, Xf_, yf_)
+            row = {"loss": loss, "grad_norm": gn,
+                   "uplink_delta": info["uplink_delta"],
+                   "cubic_iters": info["cubic_iters"]}
+            if eval_call is not None:
+                args, kwargs = eval_data_
+                row["eval"] = eval_call(*args, w_, **kwargs)
+            rows = {n: rows[n].at[t].set(row[n].astype(rows[n].dtype))
+                    for n in names}
+            return w, v, state, next_key, t + 1, gn <= tol, rows, info
+
+        w, v, state, key, ran, hit, rows, info = jax.lax.while_loop(
+            lambda c: (c[4] < bound) & ~c[5], body,
+            (w, v, state, key, jnp.int32(0), jnp.bool_(False), rows0, info0))
+        # one array, so the host pulls a stretch in one transfer; float32
+        # holds the iteration counts and the round count exactly
+        tail = jnp.zeros(STRETCH_ROUNDS).at[:2].set(
+            jnp.stack([ran, hit]).astype(jnp.float32))
+        record = jnp.stack([rows[n].astype(jnp.float32) for n in names]
+                           + [tail])
+        return w, v, state, key, record, info
+
+    def _stretch_applies(self, eval_fn, w) -> bool:
+        """Whether rounds can run as device stretches: nothing is decided
+        on the host between rounds (telemetry's round records and
+        suspicion tracking; an adaptive compressor's k, which moves the
+        round's shapes), and the evaluation traces to a scalar, probed
+        once per function."""
+        if get_telemetry().enabled or any(
+                isinstance(ch.compressor, AdaptiveTopK)
+                for ch in self.channels.values()):
+            return False
+        if eval_fn is None:
+            return True
+        call, data = _eval_parts(eval_fn)
+        if call not in self._traces:
+            try:
+                out = jax.eval_shape(
+                    lambda w, data: call(*data[0], w, **data[1]), w, data)
+                self._traces[call] = getattr(out, "shape", None) == ()
+            except (jax.errors.JAXTypeError, jax.errors.JAXIndexError):
+                self._traces[call] = False    # it needs concrete values
+        return self._traces[call]
+
     # ------------------------------------------------------------------
     def step(self, w, X, y, key, v=None, state=None):
         """One round.  Returns (w, v, state, info) where ``state`` is the
         channel-state pytree (per-worker EF memories; see
-        :meth:`init_comm_state`)."""
+        :meth:`init_comm_state`).  Where rounds run as device stretches,
+        the round is a stretch of one, with ``key`` as its key: the
+        program ``run()`` runs, compiled here."""
         self._ensure_channels(w.shape[0], X.shape[0])
         v = jnp.zeros_like(w) if v is None else v
         state = self.init_comm_state() if state is None else state
@@ -352,6 +500,13 @@ class DistributedCubicNewton:
         # the telemetry compile-counter (host-side contextvar, never
         # traced) — the compile-count regression pins read it
         with compile_scope("newton.step"):
+            if self._stretch_applies(self.eval_fn, w):
+                call, data = _eval_parts(self.eval_fn)
+                w, v, state, _, _, info = self._stretch(
+                    w, v, state, X, y, None, data, key, self._const(1),
+                    self._const(np.float32(-np.inf)), self._const(True),
+                    eval_call=call)
+                return w, v, state, info
             return self._step(w, v, state, X, y, key)
 
     # -- wire accounting ------------------------------------------------
@@ -459,30 +614,94 @@ class DistributedCubicNewton:
         entries on non-adaptive wires) — so sweep stores can pivot on
         them.
 
+        The rounds run as device stretches (:meth:`_solve_on_device`)
+        unless the host has to act between rounds: telemetry on, an
+        adaptive compressor, or an ``eval_fn`` that does not trace.  Then
+        the host loop (:meth:`_solve`) runs them one at a time.  Both give
+        the same history; ``hist["device_rounds"]`` counts the rounds
+        that ran inside stretches.
+
         ``deadline`` (a ``time.monotonic()`` timestamp) cooperatively
-        truncates the loop at the first round boundary past it — always
-        after at least one round — with ``hist["truncated"] = True``;
-        the sweep runner's per-cell wall-time budget.
+        truncates the solve at a boundary past it — always after at least
+        one round — with ``hist["truncated"] = True``; the sweep runner's
+        per-cell wall-time budget.  The host loop checks it between
+        rounds, the device path between stretches, so a solve may pass it
+        by up to one stretch (:data:`STRETCH_ROUNDS` rounds); a deadline
+        already past when the solve starts gives exactly one round.
 
         ``saddle_value`` (the problem's known f at its strict saddle, if
         any) defines the saddle-escape flag: the round whose loss first
         drops below it is the escape round (telemetry round records +
         ``hist["saddle_escape_step"]``)."""
         with get_telemetry().span("newton.solve"):
-            return self._solve(w0, X, y, n_steps, key, eval_fn, grad_tol,
-                               full_data, deadline, saddle_value)
+            self._ensure_channels(w0.shape[0], X.shape[0])
+            solve = (self._solve_on_device
+                     if self._stretch_applies(eval_fn, w0) else self._solve)
+            return solve(w0, X, y, n_steps, key, eval_fn, grad_tol,
+                         full_data, deadline, saddle_value)
+
+    def _solve_on_device(self, w0, X, y, n_steps, key, eval_fn, grad_tol,
+                         full_data, deadline, saddle_value):
+        """:meth:`run` in device stretches: one ``newton.stretch`` span a
+        stretch (``max_rounds``, its round bound), one dispatch and one
+        pull; the history is rebuilt from the pulled rows and the static
+        bits a round."""
+        key = key if key is not None else jax.random.PRNGKey(0)
+        tol = self._const(np.float32(-np.inf) if grad_tol is None
+                          else _float32_at_most(grad_tol))
+        call, data = _eval_parts(eval_fn)
+        ledger = self.ledger
+        ledger.reset()
+        hist = _empty_history()
+        tel = get_telemetry()
+        bps = self.bits_per_step()
+        w = w0
+        v, state = self._fresh_carry(w0)
+        done = 0
+        while done < n_steps:
+            late = deadline is not None and time.monotonic() >= deadline
+            if late and done:
+                hist["truncated"] = True
+                break
+            bound = 1 if late else min(STRETCH_ROUNDS, n_steps - done)
+            with tel.span("newton.stretch", max_rounds=bound), \
+                    compile_scope("newton.step"):
+                w, v, state, key, record, _ = self._stretch(
+                    w, v, state, X, y, full_data, data, key,
+                    self._const(bound), tol, self._const(False),
+                    eval_call=call)
+                record = np.asarray(record)
+            ran, hit = int(record[-1, 0]), bool(record[-1, 1])
+            rows = {n: r[:ran].tolist()
+                    for n, r in zip(_row_names(call is not None), record)}
+            rows["cubic_iters"] = [int(i) for i in rows["cubic_iters"]]
+            for i, loss in enumerate(rows["loss"]):
+                ledger.record(uplink=bps["uplink"], downlink=bps["downlink"],
+                              rounds=self.rounds_per_step, label="round")
+                hist["bits_cumulative"].append(ledger.total_bits)
+                if (saddle_value is not None
+                        and hist["saddle_escape_step"] is None
+                        and loss < saddle_value):
+                    hist["saddle_escape_step"] = done + i
+            for n, r in rows.items():
+                hist[n] += r
+            hist["k_trajectory"] += [None] * ran    # k never moves here
+            hist["device_rounds"] += ran * self.rounds_per_step
+            done += ran
+            if hit:
+                break
+        hist.update(ledger.snapshot())
+        return w, hist
 
     def _solve(self, w0, X, y, n_steps, key, eval_fn, grad_tol, full_data,
                deadline, saddle_value):
-        """The body of :meth:`run`.  Each round is a ``newton.round`` span
+        """The host loop of :meth:`run`.  Each round is a ``newton.round`` span
         with three children: ``.step`` dispatches the jitted round,
         ``.wait`` is the host blocked on it (the round's one pull),
         ``.pooled`` the pooled loss and gradient norm, one program that
         compiles in the runtime's first solve only (attributed to
         ``compile_scope("newton.pooled")``).  The rest of
         the round span is the host loop's own work."""
-        import time as _time
-
         key = key if key is not None else jax.random.PRNGKey(0)
         if full_data is None:
             full_data = (X.reshape(-1, X.shape[-1]), y.reshape(-1))
@@ -491,10 +710,7 @@ class DistributedCubicNewton:
         self._ensure_channels(w0.shape[0], X.shape[0])
         ledger = self.ledger
         ledger.reset()
-        hist = {"loss": [], "grad_norm": [], "eval": [], "rounds": 0,
-                "bits_cumulative": [], "uplink_delta": [], "cubic_iters": [],
-                "k_trajectory": [], "saddle_escape_step": None,
-                "truncated": False}
+        hist = _empty_history()
         tel = get_telemetry()
         # f(w0) anchors the first round's model decrease; only computed
         # when someone is listening (one extra loss eval)
@@ -505,7 +721,7 @@ class DistributedCubicNewton:
         state = self.init_comm_state()
         for t in range(n_steps):
             if deadline is not None and hist["loss"] \
-                    and _time.monotonic() >= deadline:
+                    and time.monotonic() >= deadline:
                 hist["truncated"] = True
                 if tel.enabled:
                     tel.event("newton.truncated", step=t)

@@ -38,8 +38,10 @@ STORE_VERSION = 1
 
 #: per-host / per-run diagnostics that must not affect merged-store bytes
 #: (wall time and the executor-pool worker pid vary run to run; stripping
-#: them is what keeps a ``--jobs N`` pool merge byte-identical to serial)
-VOLATILE_KEYS = ("wall_time_s", "worker_id")
+#: them is what keeps a ``--jobs N`` pool merge byte-identical to serial;
+#: ``device_rounds`` says how the rounds ran — in device stretches or the
+#: host loop, which telemetry being on decides — not what they computed)
+VOLATILE_KEYS = ("wall_time_s", "worker_id", "device_rounds")
 
 
 # ------------------------------------------------------------------ hash

@@ -271,13 +271,20 @@ def _profiled(log_dir, fn):
         return fn()
 
 
-def test_paper_run_spans_nest_on_the_profiler_host_plane(tmp_path, monkeypatch):
-    """With telemetry off, a profiled run puts newton.solve ⊃ newton.round
-    ⊃ {step, wait, pooled} on the host plane, one round span a round."""
+@pytest.mark.parametrize("path", ["host_loop", "stretch"])
+def test_paper_run_spans_nest_on_the_profiler_host_plane(path, tmp_path,
+                                                         monkeypatch):
+    """With telemetry off, a profiled run puts its spans on the host
+    plane: on the host loop (an adaptive compressor keeps it) newton.solve
+    ⊃ newton.round ⊃ {step, wait, pooled}, one round span a round; in
+    device stretches newton.solve ⊃ newton.stretch, one a stretch."""
     from repro.telemetry import core
 
     monkeypatch.setattr(core, "_GLOBAL", Telemetry())
-    exp = ExperimentSpec(**PAPER_KW).build()
+    kw = dict(PAPER_KW)
+    if path == "host_loop":
+        kw["compressor"] = "adaptive_topk:0.25:0.9"
+    exp = ExperimentSpec(**kw).build()
     exp.run(1)                                   # compiles outside the trace
     _, hist = _profiled(tmp_path, lambda: exp.run(3))
     spans = {}
@@ -290,6 +297,13 @@ def test_paper_run_spans_nest_on_the_profiler_host_plane(tmp_path, monkeypatch):
                    for s, e in spans[child])
 
     assert len(spans["newton.solve"]) == 1
+    if path == "stretch":
+        assert set(spans) == {"newton.solve", "newton.stretch"}
+        assert len(spans["newton.stretch"]) == 1
+        assert inside("newton.stretch", "newton.solve")
+        assert hist["device_rounds"] == hist["rounds"] == 3
+        return
+    assert hist["device_rounds"] == 0
     assert len(spans["newton.round"]) == hist["rounds"] == 3
     assert inside("newton.round", "newton.solve")
     for child in ("newton.round.step", "newton.round.wait",
@@ -427,20 +441,28 @@ def test_compile_pin_sweep_one_compile_per_cell(tmp_path):
     assert cc.backend_compiles("newton.step") == 2
 
 
-@pytest.mark.parametrize("runtime_kw, round_scopes", [
-    ({}, ("newton.step",)),
-    (dict(runtime="async", participation=0.5, staleness=1),
+@pytest.mark.parametrize("runtime_kw, telemetry_on, pooled, round_scopes", [
+    ({}, False, 0, ("newton.step",)),
+    ({}, True, 1, ("newton.step",)),
+    (dict(runtime="async", participation=0.5, staleness=1), False, 1,
      ("async.compute", "async.downlink")),
-], ids=["paper", "async"])
-def test_compile_pin_pooled_once_per_runtime(runtime_kw, round_scopes):
-    """The pooled loss-and-norm program compiles exactly once in a
-    runtime's first run; a second run with another key compiles nothing
+], ids=["paper", "paper-host-loop", "async"])
+def test_compile_pin_pooled_once_per_runtime(runtime_kw, telemetry_on, pooled,
+                                             round_scopes, request):
+    """A runtime's first run compiles its programs once: the host loops
+    the pooled loss-and-norm program and the round, the paper runtime's
+    device stretches one program under ``newton.step`` (the pooled
+    evaluation inside it); a second run with another key compiles nothing
     under ``newton.pooled`` or the runtime's round programs."""
+    if telemetry_on:                # telemetry on keeps the host loop
+        request.getfixturevalue("tel")
     exp = ExperimentSpec(**runtime_kw, **PAPER_KW).build()
     first = CompileCounter()
     with first:
         exp.run(3, key=jax.random.PRNGKey(1))
-    assert first.backend_compiles("newton.pooled") == 1
+    assert first.backend_compiles("newton.pooled") == pooled
+    if not runtime_kw:
+        assert first.backend_compiles("newton.step") == 1
     again = CompileCounter()
     with again:
         exp.run(3, key=jax.random.PRNGKey(2))
