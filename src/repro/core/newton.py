@@ -53,9 +53,9 @@ STRETCH_ROUNDS = 32
 
 def _float32_at_most(x: float) -> np.float32:
     """The largest float32 ≤ ``x``: a float32 norm ``n`` passes
-    ``n <= it`` exactly when ``float(n) <= x``, the host loop's test.
+    ``n <= it`` exactly when ``float(n) <= x``, the host's float64 test.
     (The nearest float32 may lie above ``x``, and pass a norm that the
-    host loop would not.)"""
+    host's test would not.)"""
     t = np.float32(x)
     return np.nextafter(t, np.float32(-np.inf)) if float(t) > x else t
 
@@ -78,11 +78,11 @@ def _row_names(with_eval: bool) -> list:
 
 
 def _empty_history() -> dict:
-    """The history a paper-runtime solve fills, either path."""
+    """The history a paper-runtime solve fills."""
     return {"loss": [], "grad_norm": [], "eval": [], "rounds": 0,
             "bits_cumulative": [], "uplink_delta": [], "cubic_iters": [],
             "k_trajectory": [], "saddle_escape_step": None,
-            "truncated": False, "device_rounds": 0}
+            "truncated": False}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,6 +141,9 @@ class DistributedCubicNewton:
     ``runtime_label`` names the runtime in emitted round records;
     subclasses (the async runtime) override it.
 
+    Every round runs inside a device stretch (:meth:`_stretch_impl`, one
+    jitted ``lax.while_loop`` of up to :data:`STRETCH_ROUNDS` rounds):
+    ``run()`` runs a solve as stretches, ``step()`` as a stretch of one.
     ``eval_fn`` is the evaluation ``step()`` stages with its round, so
     that a ``step()`` compiles the program ``run(eval_fn=eval_fn)`` runs.
 
@@ -205,6 +208,8 @@ class DistributedCubicNewton:
                    for name, ch in self.channels.items()
                    if isinstance(ch.compressor, AdaptiveTopK)},
             )
+        # the round alone, outside a stretch, which run() and step() never
+        # run: the benchmark lowers it, the tests run it as the reference
         self._step = jax.jit(self._step_impl)
         self._stretch = jax.jit(self._stretch_impl,
                                 static_argnames="eval_call")
@@ -297,7 +302,7 @@ class DistributedCubicNewton:
         return float(loss), float(gn)
 
     # ------------------------------------------------------------------
-    def _worker_solve(self, w, X, y, global_g):
+    def _worker_update(self, w, X, y, global_g):
         """One worker: local g, H; solve the cubic sub-problem (Eq. 2).
         Returns ``(s, iterations of Algorithm 2)``."""
         cfg = self.config
@@ -335,7 +340,7 @@ class DistributedCubicNewton:
             global_g, _ = self.aggregator(per_g)
 
         s, cubic_iters = jax.vmap(
-            lambda Xi, yi: self._worker_solve(w, Xi, yi, global_g)
+            lambda Xi, yi: self._worker_update(w, Xi, yi, global_g)
         )(X, y_used)
 
         # Uplink: honest workers δ-compress s_i (EF/EF21 memory carries the
@@ -413,9 +418,9 @@ class DistributedCubicNewton:
     def _stretch_impl(self, w, v, state, X, y, full, eval_data, key, bound,
                       tol, key_is_round_key, *, eval_call):
         """Up to ``bound`` (≤ :data:`STRETCH_ROUNDS`) rounds in one
-        ``lax.while_loop``, each as the host loop runs it: split the key,
-        the round, the pooled ``(loss, ‖∇loss‖)`` at the new iterate, the
-        evaluation.  It stops after the first round whose norm is
+        ``lax.while_loop``, each as a round at a time would run it: split
+        the key, the round, the pooled ``(loss, ‖∇loss‖)`` at the new
+        iterate, the evaluation.  It stops after the first round whose norm is
         ≤ ``tol``.  Returns the state after the last round, the key to go
         on from, the stretch's *record* and the last round's ``info``.
         The record is float32 ``(len(names) + 1, STRETCH_ROUNDS)``: one
@@ -437,9 +442,13 @@ class DistributedCubicNewton:
             w, v, state, key, t, _, rows, _ = c
             next_key, sub = jax.random.split(key)
             sub = jnp.where(key_is_round_key & (t == 0), key, sub)
-            w, v, state, info = self._step_impl(w, v, state, X, y, sub)
-            # the barrier keeps the evaluations from fusing with the round,
-            # so they read as the host loop's own programs, to the last bit
+            # the barriers keep the round and the evaluations from fusing
+            # with each other, with the last round or with the data the
+            # loop holds fixed: each reads as its own separately jitted
+            # program (``_step``, ``_pooled``), to the last bit
+            w, v, state, X_, y_, sub = jax.lax.optimization_barrier(
+                (w, v, state, X, y, sub))
+            w, v, state, info = self._step_impl(w, v, state, X_, y_, sub)
             w_, Xf_, yf_, eval_data_ = jax.lax.optimization_barrier(
                 (w, Xf, yf, eval_data))
             loss, gn = self._pooled_impl(w_, Xf_, yf_)
@@ -464,18 +473,13 @@ class DistributedCubicNewton:
                            + [tail])
         return w, v, state, key, record, info
 
-    def _stretch_applies(self, eval_fn, w) -> bool:
-        """Whether rounds can run as device stretches: nothing is decided
-        on the host between rounds (telemetry's round records and
-        suspicion tracking; an adaptive compressor's k, which moves the
-        round's shapes), and the evaluation traces to a scalar, probed
-        once per function."""
-        if get_telemetry().enabled or any(
-                isinstance(ch.compressor, AdaptiveTopK)
-                for ch in self.channels.values()):
-            return False
+    def _staged_eval(self, eval_fn, w):
+        """``(eval_call, eval_data)`` a stretch stages for ``eval_fn``: its
+        parts when it traces to a scalar (probed once per function), else
+        ``(None, ((), {}))``, and the host calls it after each round."""
+        unstaged = (None, ((), {}))
         if eval_fn is None:
-            return True
+            return unstaged
         call, data = _eval_parts(eval_fn)
         if call not in self._traces:
             try:
@@ -484,30 +488,28 @@ class DistributedCubicNewton:
                 self._traces[call] = getattr(out, "shape", None) == ()
             except (jax.errors.JAXTypeError, jax.errors.JAXIndexError):
                 self._traces[call] = False    # it needs concrete values
-        return self._traces[call]
+        return (call, data) if self._traces[call] else unstaged
 
     # ------------------------------------------------------------------
     def step(self, w, X, y, key, v=None, state=None):
         """One round.  Returns (w, v, state, info) where ``state`` is the
         channel-state pytree (per-worker EF memories; see
-        :meth:`init_comm_state`).  Where rounds run as device stretches,
-        the round is a stretch of one, with ``key`` as its key: the
-        program ``run()`` runs, compiled here."""
+        :meth:`init_comm_state`).  The round is a device stretch of one,
+        with ``key`` as its key and ``self.eval_fn`` staged when it
+        traces: the program ``run()`` runs, compiled here."""
         self._ensure_channels(w.shape[0], X.shape[0])
         v = jnp.zeros_like(w) if v is None else v
         state = self.init_comm_state() if state is None else state
+        call, data = self._staged_eval(self.eval_fn, w)
         # every (re)compile of the step is attributed to this scope by
         # the telemetry compile-counter (host-side contextvar, never
         # traced) — the compile-count regression pins read it
         with compile_scope("newton.step"):
-            if self._stretch_applies(self.eval_fn, w):
-                call, data = _eval_parts(self.eval_fn)
-                w, v, state, _, _, info = self._stretch(
-                    w, v, state, X, y, None, data, key, self._const(1),
-                    self._const(np.float32(-np.inf)), self._const(True),
-                    eval_call=call)
-                return w, v, state, info
-            return self._step(w, v, state, X, y, key)
+            w, v, state, _, _, info = self._stretch(
+                w, v, state, X, y, None, data, key, self._const(1),
+                self._const(np.float32(-np.inf)), self._const(True),
+                eval_call=call)
+        return w, v, state, info
 
     # -- wire accounting ------------------------------------------------
     def bits_per_step(self) -> dict:
@@ -614,169 +616,109 @@ class DistributedCubicNewton:
         entries on non-adaptive wires) — so sweep stores can pivot on
         them.
 
-        The rounds run as device stretches (:meth:`_solve_on_device`)
-        unless the host has to act between rounds: telemetry on, an
-        adaptive compressor, or an ``eval_fn`` that does not trace.  Then
-        the host loop (:meth:`_solve`) runs them one at a time.  Both give
-        the same history; ``hist["device_rounds"]`` counts the rounds
-        that ran inside stretches.
+        The rounds run as device stretches: one ``newton.stretch`` span a
+        stretch (``max_rounds``, its round bound), one dispatch and one
+        pull, up to :data:`STRETCH_ROUNDS` rounds with the ε test, the
+        pooled evaluation and a tracing ``eval_fn`` inside.  Where the host
+        acts between rounds — telemetry on (round records, suspicion), an
+        adaptive compressor (its k moves the round's shapes), an
+        ``eval_fn`` that does not trace — each stretch is one round, and
+        the host does that work after it.
 
         ``deadline`` (a ``time.monotonic()`` timestamp) cooperatively
-        truncates the solve at a boundary past it — always after at least
-        one round — with ``hist["truncated"] = True``; the sweep runner's
-        per-cell wall-time budget.  The host loop checks it between
-        rounds, the device path between stretches, so a solve may pass it
-        by up to one stretch (:data:`STRETCH_ROUNDS` rounds); a deadline
-        already past when the solve starts gives exactly one round.
+        truncates the solve at a stretch boundary past it — always after
+        at least one round — with ``hist["truncated"] = True``; the sweep
+        runner's per-cell wall-time budget.  A solve may pass it by up to
+        one stretch (:data:`STRETCH_ROUNDS` rounds); a deadline already
+        past when the solve starts gives exactly one round.
 
         ``saddle_value`` (the problem's known f at its strict saddle, if
         any) defines the saddle-escape flag: the round whose loss first
         drops below it is the escape round (telemetry round records +
         ``hist["saddle_escape_step"]``)."""
-        with get_telemetry().span("newton.solve"):
+        tel = get_telemetry()
+        with tel.span("newton.solve"):
             self._ensure_channels(w0.shape[0], X.shape[0])
-            solve = (self._solve_on_device
-                     if self._stretch_applies(eval_fn, w0) else self._solve)
-            return solve(w0, X, y, n_steps, key, eval_fn, grad_tol,
-                         full_data, deadline, saddle_value)
-
-    def _solve_on_device(self, w0, X, y, n_steps, key, eval_fn, grad_tol,
-                         full_data, deadline, saddle_value):
-        """:meth:`run` in device stretches: one ``newton.stretch`` span a
-        stretch (``max_rounds``, its round bound), one dispatch and one
-        pull; the history is rebuilt from the pulled rows and the static
-        bits a round."""
-        key = key if key is not None else jax.random.PRNGKey(0)
-        tol = self._const(np.float32(-np.inf) if grad_tol is None
-                          else _float32_at_most(grad_tol))
-        call, data = _eval_parts(eval_fn)
-        ledger = self.ledger
-        ledger.reset()
-        hist = _empty_history()
-        tel = get_telemetry()
-        bps = self.bits_per_step()
-        w = w0
-        v, state = self._fresh_carry(w0)
-        done = 0
-        while done < n_steps:
-            late = deadline is not None and time.monotonic() >= deadline
-            if late and done:
-                hist["truncated"] = True
-                break
-            bound = 1 if late else min(STRETCH_ROUNDS, n_steps - done)
-            with tel.span("newton.stretch", max_rounds=bound), \
-                    compile_scope("newton.step"):
-                w, v, state, key, record, _ = self._stretch(
-                    w, v, state, X, y, full_data, data, key,
-                    self._const(bound), tol, self._const(False),
-                    eval_call=call)
-                record = np.asarray(record)
-            ran, hit = int(record[-1, 0]), bool(record[-1, 1])
-            rows = {n: r[:ran].tolist()
-                    for n, r in zip(_row_names(call is not None), record)}
-            rows["cubic_iters"] = [int(i) for i in rows["cubic_iters"]]
-            for i, loss in enumerate(rows["loss"]):
-                ledger.record(uplink=bps["uplink"], downlink=bps["downlink"],
-                              rounds=self.rounds_per_step, label="round")
-                hist["bits_cumulative"].append(ledger.total_bits)
-                if (saddle_value is not None
-                        and hist["saddle_escape_step"] is None
-                        and loss < saddle_value):
-                    hist["saddle_escape_step"] = done + i
-            for n, r in rows.items():
-                hist[n] += r
-            hist["k_trajectory"] += [None] * ran    # k never moves here
-            hist["device_rounds"] += ran * self.rounds_per_step
-            done += ran
-            if hit:
-                break
-        hist.update(ledger.snapshot())
-        return w, hist
-
-    def _solve(self, w0, X, y, n_steps, key, eval_fn, grad_tol, full_data,
-               deadline, saddle_value):
-        """The host loop of :meth:`run`.  Each round is a ``newton.round`` span
-        with three children: ``.step`` dispatches the jitted round,
-        ``.wait`` is the host blocked on it (the round's one pull),
-        ``.pooled`` the pooled loss and gradient norm, one program that
-        compiles in the runtime's first solve only (attributed to
-        ``compile_scope("newton.pooled")``).  The rest of
-        the round span is the host loop's own work."""
-        key = key if key is not None else jax.random.PRNGKey(0)
-        if full_data is None:
-            full_data = (X.reshape(-1, X.shape[-1]), y.reshape(-1))
-        Xf, yf = full_data
-
-        self._ensure_channels(w0.shape[0], X.shape[0])
-        ledger = self.ledger
-        ledger.reset()
-        hist = _empty_history()
-        tel = get_telemetry()
-        # f(w0) anchors the first round's model decrease; only computed
-        # when someone is listening (one extra loss eval)
-        prev_loss = self._pooled_eval(w0, Xf, yf)[0] if tel.enabled else None
-        tracker = SuspicionTracker(X.shape[0]) if tel.enabled else None
-        w = w0
-        v = jnp.zeros_like(w0)
-        state = self.init_comm_state()
-        for t in range(n_steps):
-            if deadline is not None and hist["loss"] \
-                    and time.monotonic() >= deadline:
-                hist["truncated"] = True
-                if tel.enabled:
-                    tel.event("newton.truncated", step=t)
-                break
-            with tel.span("newton.round", step=t):
-                key, sub = jax.random.split(key)
-                k_live = self._uplink_k()      # the k this round transmits at
-                with tel.span("newton.round.step"):
-                    w, v, state, info = self.step(w, X, y, sub, v, state)
-                # re-read every step: adaptive compressors move k between steps
-                bps = self.bits_per_step()
-                ledger.record(uplink=bps["uplink"], downlink=bps["downlink"],
-                              rounds=self.rounds_per_step, label="round")
-                hist["bits_cumulative"].append(ledger.total_bits)
-                with tel.span("newton.round.wait"):
-                    delta_hat, cubic_iters = jax.device_get(
-                        (info["uplink_delta"], info["cubic_iters"]))
-                delta_hat = float(delta_hat)
-                hist["uplink_delta"].append(delta_hat)
-                hist["cubic_iters"].append(int(cubic_iters))
-                hist["k_trajectory"].append(k_live)
-                with tel.span("newton.round.pooled"):
-                    loss, gn = self._pooled_eval(w, Xf, yf)
-                hist["loss"].append(loss)
-                hist["grad_norm"].append(gn)
-                if eval_fn is not None:
-                    hist["eval"].append(float(eval_fn(w)))
-                hit_tol = grad_tol is not None and gn <= grad_tol
-                k_changed = False
-                if not hit_tol:
-                    k_changed = self._maybe_adapt(gn, measured_delta=delta_hat)
-                escaped = (saddle_value is not None
-                           and hist["saddle_escape_step"] is None
-                           and loss < saddle_value)
-                if escaped:
-                    hist["saddle_escape_step"] = t
-                if tel.enabled:
-                    tel.round(RoundRecord(
-                        step=t, runtime=self.runtime_label, loss=loss,
-                        grad_norm=gn,
-                        model_decrease=(None if prev_loss is None
-                                        else prev_loss - loss),
-                        uplink_delta=delta_hat, k=k_live,
-                        k_changed=k_changed, saddle_escape=escaped,
-                        rejected=rejected_from_keep(info["keep"]),
-                        attack=self.attack.name, alpha=self.attack.alpha,
-                        wire_uplink_bits=bps["uplink"],
-                        wire_downlink_bits=bps["downlink"],
-                        center_bytes=self.center_bytes_per_round(),
-                        agg_kernel=self._agg_kernel_label(),
-                        **self._worker_round_fields(info, X.shape[0], bps,
-                                                    tracker),
-                    ), name="newton.round")
-                    prev_loss = loss
-            if hit_tol:
-                break
-        hist.update(ledger.snapshot())
-        return w, hist
+            key = key if key is not None else jax.random.PRNGKey(0)
+            tol = self._const(np.float32(-np.inf) if grad_tol is None
+                              else _float32_at_most(grad_tol))
+            call, data = self._staged_eval(eval_fn, w0)
+            host_eval = eval_fn if call is None else None
+            host_rounds = tel.enabled or host_eval is not None or any(
+                isinstance(ch.compressor, AdaptiveTopK)
+                for ch in self.channels.values())
+            ledger = self.ledger
+            ledger.reset()
+            hist = _empty_history()
+            if tel.enabled:
+                # f(w0) anchors the first round's model decrease
+                Xf, yf = full_data if full_data is not None else (
+                    X.reshape(-1, X.shape[-1]), y.reshape(-1))
+                prev_loss = self._pooled_eval(w0, Xf, yf)[0]
+                tracker = SuspicionTracker(X.shape[0])
+            w = w0
+            v, state = self._fresh_carry(w0)
+            done = 0
+            while done < n_steps:
+                late = deadline is not None and time.monotonic() >= deadline
+                if late and done:
+                    hist["truncated"] = True
+                    if tel.enabled:
+                        tel.event("newton.truncated", step=done)
+                    break
+                bound = (1 if late or host_rounds
+                         else min(STRETCH_ROUNDS, n_steps - done))
+                k_live = self._uplink_k()   # the k this stretch transmits at
+                bps = self.bits_per_step()  # adaptive k moves it
+                with tel.span("newton.stretch", max_rounds=bound), \
+                        compile_scope("newton.step"):
+                    w, v, state, key, record, info = self._stretch(
+                        w, v, state, X, y, full_data, data, key,
+                        self._const(bound), tol, self._const(False),
+                        eval_call=call)
+                    record = np.asarray(record)
+                ran, hit = int(record[-1, 0]), bool(record[-1, 1])
+                rows = {n: r[:ran].tolist()
+                        for n, r in zip(_row_names(call is not None), record)}
+                rows["cubic_iters"] = [int(i) for i in rows["cubic_iters"]]
+                for i, loss in enumerate(rows["loss"]):
+                    ledger.record(uplink=bps["uplink"],
+                                  downlink=bps["downlink"],
+                                  rounds=self.rounds_per_step, label="round")
+                    hist["bits_cumulative"].append(ledger.total_bits)
+                    if (saddle_value is not None
+                            and hist["saddle_escape_step"] is None
+                            and loss < saddle_value):
+                        hist["saddle_escape_step"] = done + i
+                for n, r in rows.items():
+                    hist[n] += r
+                hist["k_trajectory"] += [k_live] * ran
+                if host_rounds:     # the stretch ran the one round ``done``
+                    (loss,), (gn,) = rows["loss"], rows["grad_norm"]
+                    (delta_hat,) = rows["uplink_delta"]
+                    if host_eval is not None:
+                        hist["eval"].append(float(host_eval(w)))
+                    k_changed = not hit and self._maybe_adapt(
+                        gn, measured_delta=delta_hat)
+                    if tel.enabled:
+                        tel.round(RoundRecord(
+                            step=done, runtime=self.runtime_label, loss=loss,
+                            grad_norm=gn, model_decrease=prev_loss - loss,
+                            uplink_delta=delta_hat, k=k_live,
+                            k_changed=k_changed,
+                            saddle_escape=hist["saddle_escape_step"] == done,
+                            rejected=rejected_from_keep(info["keep"]),
+                            attack=self.attack.name, alpha=self.attack.alpha,
+                            wire_uplink_bits=bps["uplink"],
+                            wire_downlink_bits=bps["downlink"],
+                            center_bytes=self.center_bytes_per_round(),
+                            agg_kernel=self._agg_kernel_label(),
+                            **self._worker_round_fields(info, X.shape[0], bps,
+                                                        tracker),
+                        ), name="newton.round")
+                        prev_loss = loss
+                done += ran
+                if hit:
+                    break
+            hist.update(ledger.snapshot())
+            return w, hist

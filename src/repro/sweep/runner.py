@@ -90,8 +90,7 @@ def execute_cell(entry: PlanEntry, *,
     to fail deliberately) is the cross-process test seam — monkeypatches
     don't survive a spawn, a plain argument does.
 
-    The record's ``wall_time_s``, ``worker_id`` and ``device_rounds``
-    (the run's rounds that ran in device stretches) are *volatile* store
+    The record's ``wall_time_s`` and ``worker_id`` are *volatile* store
     fields: per-run diagnostics stripped on merge, keeping pool and
     serial merged stores byte-identical.
     """
@@ -112,8 +111,6 @@ def execute_cell(entry: PlanEntry, *,
                 raise RuntimeError(f"injected failure for cell {h}")
             record["status"] = "ok"
             record["metrics"] = _build_and_run(entry, deadline)
-            record["device_rounds"] = record["metrics"].pop(
-                "device_rounds", 0)
         except Exception as e:   # noqa: BLE001 — failure isolation is the point
             record["status"] = "failed"
             record.pop("metrics", None)

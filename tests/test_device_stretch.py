@@ -1,14 +1,16 @@
 """Rounds run as device stretches: one ``lax.while_loop`` program a stretch,
 with the ε test, the pooled evaluation and the test accuracy inside.
 
-Pins that a stretch gives the host loop's solve exactly (same iterate, same
-history, same round at which ε is met), that ``run()`` takes the host loop
+Pins that a stretch gives, exactly, the solve of a round-at-a-time
+reference over the separately jitted programs (same iterate, same history,
+same round at which ε is met), that ``run()`` runs one round a stretch
 wherever the host must act between rounds, and that the stretch compiles
 once per runtime, in ``step()`` or the first ``run()``, never again.
 """
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -37,12 +39,64 @@ CASES = ([(c, case) for c in CONFIGS
          + [(c, "two-stretches") for c in ("gauss-normtrim", "topk-ef21")])
 
 
-def _host_loop(exp, n_steps, key, grad_tol=None, deadline=None):
+def _round_at_a_time(exp, n_steps, key, grad_tol=None, eval_fn=None):
+    """The reference solve: one round at a time over the separately jitted
+    programs (the round ``algo._step``, the pooled loss and norm
+    ``algo._pooled``) and the eager evaluation, with the history, the
+    stop at ε, the saddle escape and the bits a round kept here; an
+    adaptive compressor moves its k after each round by its own rule."""
     algo, prob = exp.algo, exp.problem
     algo._ensure_channels(prob.dim, prob.m_workers)
-    return algo._solve(prob.w0, prob.X_workers, prob.y_workers, n_steps, key,
-                       prob.eval_fn, grad_tol, None, deadline,
-                       prob.saddle_value)
+    eval_fn = prob.eval_fn if eval_fn is None else eval_fn
+    X, y = prob.X_workers, prob.y_workers
+    Xf, yf = X.reshape(-1, X.shape[-1]), y.reshape(-1)
+    w, v, state = prob.w0, jnp.zeros_like(prob.w0), algo.init_comm_state()
+    hist = {"loss": [], "grad_norm": [], "eval": [], "bits_cumulative": [],
+            "uplink_delta": [], "cubic_iters": [], "k_trajectory": [],
+            "saddle_escape_step": None, "truncated": False}
+    up = down = 0
+    for t in range(n_steps):
+        key, sub = jax.random.split(key)
+        k, bps = algo._uplink_k(), algo.bits_per_step()
+        w, v, state, info = algo._step(w, v, state, X, y, sub)
+        loss, gn = (float(a) for a in algo._pooled(w, Xf, yf))
+        delta = float(info["uplink_delta"])
+        hist["loss"].append(loss)
+        hist["grad_norm"].append(gn)
+        hist["uplink_delta"].append(delta)
+        hist["cubic_iters"].append(int(info["cubic_iters"]))
+        hist["k_trajectory"].append(k)
+        if eval_fn is not None:
+            hist["eval"].append(float(eval_fn(w)))
+        up, down = up + bps["uplink"], down + bps["downlink"]
+        hist["bits_cumulative"].append(up + down)
+        if (prob.saddle_value is not None
+                and hist["saddle_escape_step"] is None
+                and loss < prob.saddle_value):
+            hist["saddle_escape_step"] = t
+        if grad_tol is not None and gn <= grad_tol:
+            break
+        algo._maybe_adapt(gn, measured_delta=delta)
+    hist.update(uplink_bits=up, downlink_bits=down, total_bits=up + down,
+                rounds=len(hist["loss"]) * algo.rounds_per_step)
+    return w, hist
+
+
+def _stretch_bounds(monkeypatch, tel):
+    """Makes ``tel`` the global telemetry; returns the list that gets the
+    round bound of each ``newton.stretch`` span it opens."""
+    from repro.telemetry import core
+
+    bounds, span = [], tel.span
+
+    def spy(name, **attrs):
+        if name == "newton.stretch":
+            bounds.append(attrs["max_rounds"])
+        return span(name, **attrs)
+
+    monkeypatch.setattr(tel, "span", spy)
+    monkeypatch.setattr(core, "_GLOBAL", tel)
+    return bounds
 
 
 def _below(x):
@@ -52,19 +106,17 @@ def _below(x):
     return float(x) - 0.25 * (float(x) - float(np.nextafter(x, np.float32(0))))
 
 
-def _assert_same_solve(stretch, host):
-    (w_s, h_s), (w_h, h_h) = stretch, host
-    np.testing.assert_array_equal(np.asarray(w_s), np.asarray(w_h))
-    assert h_h["device_rounds"] == 0
-    assert h_s["device_rounds"] == h_s["rounds"]
-    assert {k: v for k, v in h_s.items() if k != "device_rounds"} \
-        == {k: v for k, v in h_h.items() if k != "device_rounds"}
+def _assert_same_history(stretch, reference):
+    (w_s, h_s), (w_r, h_r) = stretch, reference
+    np.testing.assert_array_equal(np.asarray(w_s), np.asarray(w_r))
+    assert h_s == h_r
 
 
 @pytest.mark.parametrize("config, case", CASES)
 def test_stretch_gives_the_host_loops_solve(config, case):
     """Every list of the history, the exact bits, the rounds, the saddle
-    escape and the iterate match the host loop's on the same key."""
+    escape and the iterate match the round-at-a-time reference's on the
+    same key."""
     exp = ExperimentSpec(**CONFIGS[config]).build()
     if config == "sparse-center":
         exp.algo._ensure_channels(exp.problem.dim, exp.problem.m_workers)
@@ -74,12 +126,13 @@ def test_stretch_gives_the_host_loops_solve(config, case):
     if case == "two-stretches":
         n_steps = newton.STRETCH_ROUNDS + 3
     elif case != "cap":
-        _, ref = _host_loop(exp, 4, key)
+        _, ref = _round_at_a_time(exp, 4, key)
         gn = ref["grad_norm"]
         i = int(np.argmin(gn[:3]))          # the lowest norm of rounds 0-2
         grad_tol = gn[i] if case == "eps-mid-stretch" else _below(gn[i])
     stretch = exp.run(n_steps, key=key, grad_tol=grad_tol)
-    _assert_same_solve(stretch, _host_loop(exp, n_steps, key, grad_tol))
+    _assert_same_history(stretch,
+                         _round_at_a_time(exp, n_steps, key, grad_tol))
     hist = stretch[1]
     if case == "two-stretches":
         assert len(hist["loss"]) == n_steps > newton.STRETCH_ROUNDS
@@ -101,10 +154,12 @@ def test_float32_at_most_is_the_host_test():
         assert float(t) <= x < float(np.nextafter(t, np.float32(np.inf)))
 
 
-def test_stretch_runs_every_round_with_fixed_compressors():
+def test_stretch_runs_every_round_with_fixed_compressors(monkeypatch):
+    bounds = _stretch_bounds(monkeypatch, Telemetry())
     exp = ExperimentSpec(**CONFIGS["topk-ef21"]).build()
     _, hist = exp.run(5, key=jax.random.PRNGKey(1))
-    assert hist["device_rounds"] == hist["rounds"] == 5
+    assert hist["rounds"] == 5
+    assert bounds == [5]              # all five rounds in one stretch
 
 
 def _untraceable_accuracy(w):
@@ -115,34 +170,38 @@ def _untraceable_accuracy(w):
                                  "untraceable_eval"])
 def test_host_loop_where_the_host_decides(why, tmp_path, monkeypatch):
     """Telemetry on, an adaptive compressor and an ``eval_fn`` that needs
-    concrete values each keep the host loop: no round runs on the device."""
-    from repro.telemetry import core
-
+    concrete values each make every stretch one round, with the host's
+    work between them; the solve is the round-at-a-time reference's."""
     tel = Telemetry()
     if why == "telemetry":
         tel.enable(str(tmp_path / "telemetry"))
-    monkeypatch.setattr(core, "_GLOBAL", tel)
+    bounds = _stretch_bounds(monkeypatch, tel)
     kw = dict(SMALL)
     if why == "adaptive_topk":
         kw["compressor"] = "adaptive_topk:0.25:0.9"
     exp = ExperimentSpec(**kw).build()
     eval_fn = _untraceable_accuracy if why == "untraceable_eval" else None
+    key = jax.random.PRNGKey(2)
     try:
-        _, hist = exp.run(3, key=jax.random.PRNGKey(2), eval_fn=eval_fn)
+        solve = exp.run(3, key=key, eval_fn=eval_fn)
     finally:
         tel.disable()
-    assert hist["rounds"] == 3
-    assert hist["device_rounds"] == 0
+    assert solve[1]["rounds"] == 3
+    assert bounds == [1, 1, 1]        # one stretch a round
+    _assert_same_history(solve, _round_at_a_time(
+        ExperimentSpec(**kw).build(), 3, key, eval_fn=eval_fn))
     if why == "untraceable_eval":
-        assert len(hist["eval"]) == 3
+        assert len(solve[1]["eval"]) == 3
         assert exp.algo._traces == {_untraceable_accuracy: False}
 
 
-def test_past_deadline_gives_one_round_truncated():
+def test_past_deadline_gives_one_round_truncated(monkeypatch):
+    bounds = _stretch_bounds(monkeypatch, Telemetry())
     exp = ExperimentSpec(**SMALL).build()
     _, hist = exp.run(10, key=jax.random.PRNGKey(3),
                       deadline=time.monotonic() - 1.0)
-    assert hist["rounds"] == hist["device_rounds"] == 1
+    assert hist["rounds"] == 1
+    assert bounds == [1]
     assert hist["truncated"] is True
 
 
@@ -158,7 +217,7 @@ def test_second_run_compiles_nothing():
     assert again.backend_compiles(ANY) == 0
 
 
-def test_step_compiles_what_run_runs():
+def test_step_compiles_what_run_runs(monkeypatch):
     """A ``step()`` compiles the stretch, test accuracy included, and
     returns the round's ``info``; a ``run()`` after it compiles nothing."""
     exp = ExperimentSpec(**CONFIGS["logistic-accuracy"]).build()
@@ -175,8 +234,9 @@ def test_step_compiles_what_run_runs():
                                prob.X_workers, prob.y_workers,
                                jax.random.PRNGKey(0))
     np.testing.assert_array_equal(np.asarray(w), np.asarray(w_ref))
+    bounds = _stretch_bounds(monkeypatch, Telemetry())
     after = CompileCounter()
     with after:
         _, hist = exp.run(3, key=jax.random.PRNGKey(4), grad_tol=1e-6)
     assert after.backend_compiles(ANY) == 0
-    assert hist["device_rounds"] == 3 and len(hist["eval"]) == 3
+    assert bounds == [3] and hist["rounds"] == len(hist["eval"]) == 3

@@ -142,16 +142,20 @@ def test_pooled_program_matches_separate_passes(problem):
     assert float(gn) == pytest.approx(
         float(jnp.linalg.norm(gradf(w, X, y))), rel=1e-6)
 
-    # a run's device stretches evaluate inside their program; the host
-    # loop, given the two separate passes, reads the same
+    # a run's device stretches evaluate inside their program; the rounds
+    # run one at a time, with the two separate passes after each, read
+    # the same
     key = jax.random.PRNGKey(7)
     _, fused = exp.run(4, key=key)
-    assert fused["device_rounds"] == 4
-    algo._pooled = lambda w, X, y: (lossf(w, X, y),
-                                    jnp.linalg.norm(gradf(w, X, y)))
-    _, separate = algo._solve(prob.w0, prob.X_workers, prob.y_workers, 4, key,
-                              None, None, None, None, None)
-    assert fused["rounds"] == separate["rounds"] == 4
-    assert fused["total_bits"] == separate["total_bits"]
+    w, v, state = prob.w0, jnp.zeros_like(prob.w0), algo.init_comm_state()
+    separate = {"loss": [], "grad_norm": []}
+    for _ in range(4):
+        key, sub = jax.random.split(key)
+        w, v, state, _ = algo._step(w, v, state, prob.X_workers,
+                                    prob.y_workers, sub)
+        separate["loss"].append(float(lossf(w, X, y)))
+        separate["grad_norm"].append(float(jnp.linalg.norm(gradf(w, X, y))))
+    assert fused["rounds"] == 4
+    assert fused["total_bits"] == 4 * sum(algo.bits_per_step().values())
     for name in ("loss", "grad_norm"):
         assert fused[name] == pytest.approx(separate[name], rel=1e-6)
