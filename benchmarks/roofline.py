@@ -59,7 +59,7 @@ def roofline_table(records=None):
 def kernel_microbench(n_iter=3):
     """CPU interpret-mode wall time (correctness-path cost only) + the
     TPU-roofline-derived time for each kernel's benchmark shape."""
-    from repro.kernels import cubic_step, flash_attention, rmsnorm
+    from repro.kernels import flash_attention, rmsnorm
     from repro.launch.hlo import HBM_BW, PEAK_FLOPS
 
     out = []
@@ -74,18 +74,6 @@ def kernel_microbench(n_iter=3):
     flops = 4 * B * H * S * S * Dh / 2  # causal
     out.append(("flash_attention_512", (time.time() - t0) / n_iter * 1e6,
                 flops / PEAK_FLOPS * 1e6))
-
-    d = 300
-    Hm = jnp.eye(d)
-    g = jnp.ones((d,))
-    s = jnp.ones((d,))
-    f = lambda: cubic_step(s, g, Hm, M=10.0, gamma=1.0, lr=1e-2).block_until_ready()
-    f()
-    t0 = time.time()
-    for _ in range(n_iter):
-        f()
-    out.append(("cubic_step_d300", (time.time() - t0) / n_iter * 1e6,
-                (d * d * 4) / HBM_BW * 1e6))
 
     x = jnp.ones((512, 1024), jnp.float32)
     w = jnp.ones((1024,), jnp.float32)

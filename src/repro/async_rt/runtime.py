@@ -124,9 +124,7 @@ class AsyncCubicNewton(DistributedCubicNewton):
         """
         k_label, k_update, k_comp, _k_grad, _k_down = jax.random.split(key, 5)
         y_used = self._attack_rule.corrupt_labels(k_label, y)
-        s, _ = jax.vmap(
-            lambda Xi, yi: self._worker_update(w, Xi, yi, None)
-        )(X, y_used)
+        s, _ = self._worker_updates(w, X, y_used, None)
         if get_telemetry().enabled:
             # forensics: also stage the per-sender δ̂ (trace-time gate —
             # the disabled program is the exact pre-forensics HLO)

@@ -12,7 +12,10 @@ Three solvers are provided:
 * :func:`solve_cubic_gd` — the paper's Algorithm 2: plain gradient descent on
   the sub-problem with explicit H, run as a ``lax.while_loop`` on ‖G‖ > τ
   (iteration-capped so it always terminates under jit);
-  :func:`solve_cubic_gd_counted` also returns the loop's trip count.
+  :func:`solve_cubic_gd_counted` also returns the loop's trip count, and
+  :func:`solve_cubic_gd_batched` runs it for m workers at once — as one
+  Pallas kernel on a TPU where the Hessians fit in VMEM
+  (:func:`cubic_kernel_plan`), else as the vmapped loop.
 * :func:`solve_cubic_hvp` — matrix-free Algorithm 2 for pytree parameters:
   H·s is a Hessian-vector product closure (two backprops), the loop is a
   ``lax.fori_loop`` with a fixed iteration count so the distributed train
@@ -29,6 +32,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.cubic_solve import cubic_solve_stacked, cubic_stack_bytes
 from .tree_util import (
     tree_axpy,
     tree_norm,
@@ -106,6 +110,11 @@ def solve_cubic_gd(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6, max_iters=2000):
     return solve_cubic_gd_counted(g, H, M, gamma, lr, tol, max_iters)[0]
 
 
+def _default_lr(H, M, gamma):
+    """1/(γ(‖H‖_F+Mγ)): a safe step for the smooth part of the sub-problem."""
+    return 1.0 / (gamma * (jnp.linalg.norm(H, ord="fro") + M * gamma) + 1e-8)
+
+
 @partial(jax.jit, static_argnames=("max_iters",))
 def solve_cubic_gd_counted(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6,
                            max_iters=2000):
@@ -113,8 +122,7 @@ def solve_cubic_gd_counted(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6,
     ``(s, iterations)``, an int32 — the data-dependent factor in the
     round's device time."""
     if lr is None:
-        # 1/(γ(‖H‖+Mγ)) is a safe step for the smooth part of the sub-problem.
-        lr = 1.0 / (gamma * (jnp.linalg.norm(H, ord="fro") + M * gamma) + 1e-8)
+        lr = _default_lr(H, M, gamma)
 
     def cond(state):
         it, s, G = state
@@ -128,6 +136,42 @@ def solve_cubic_gd_counted(g, H, M=10.0, gamma=1.0, lr=None, tol=1e-6,
 
     it, s, _ = jax.lax.while_loop(cond, body, (0, jnp.zeros_like(g), g))
     return s, it
+
+
+# The transposed, padded Hessian stack the kernel may hold in VMEM (a TPU
+# v5e core has 128 MiB of it); a larger stack takes the vmapped loop
+CUBIC_VMEM_BUDGET = 48 * 2**20
+# workers the kernel unrolls its matvec over
+CUBIC_MAX_WORKERS = 128
+
+
+def cubic_kernel_plan(m: int, d: int) -> str:
+    """Which implementation :func:`solve_cubic_gd_batched` runs for m
+    workers at dimension d: ``"pallas"`` (one kernel launch) when JAX's
+    backend is a TPU, m ≤ :data:`CUBIC_MAX_WORKERS` and the padded Hessian
+    stack fits :data:`CUBIC_VMEM_BUDGET`; else ``"xla"`` (the vmapped
+    ``while_loop``): on the CPU, and at large d."""
+    if (jax.default_backend() == "tpu" and m <= CUBIC_MAX_WORKERS
+            and cubic_stack_bytes(m, d) <= CUBIC_VMEM_BUDGET):
+        return "pallas"
+    return "xla"
+
+
+def solve_cubic_gd_batched(g, H, M=10.0, gamma=1.0, tol=1e-6, max_iters=2000):
+    """Algorithm 2 for m workers at once: g (m, d), H (m, d, d) →
+    ``(s (m, d), iterations (m,) int32)``, worker i stopping at its own
+    count as :func:`solve_cubic_gd_counted` alone would.  The kernel sums
+    in another order, so its s may differ in the last bits, and a count
+    by one where ‖G‖ meets τ within float32 rounding.  ``M``, ``gamma``,
+    ``tol`` and ``max_iters`` are Python numbers; the implementation is
+    :func:`cubic_kernel_plan`'s for the shapes."""
+    m, d = g.shape
+    if cubic_kernel_plan(m, d) == "pallas":
+        lr = jax.vmap(_default_lr, in_axes=(0, None, None))(H, M, gamma)
+        return cubic_solve_stacked(g, H, lr, M=M, gamma=gamma, tol=tol,
+                                   max_iters=max_iters)
+    return jax.vmap(lambda gi, Hi: solve_cubic_gd_counted(
+        gi, Hi, M=M, gamma=gamma, tol=tol, max_iters=max_iters))(g, H)
 
 
 # ---------------------------------------------------------------------------

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .cubic import solve_cubic_gd_counted
+from .cubic import cubic_kernel_plan, solve_cubic_gd_batched
 from ..comm import VectorChannel, WireLedger
 from ..compression import AdaptiveTopK
 from ..telemetry import (
@@ -190,6 +190,9 @@ class DistributedCubicNewton:
         # channels need (d, m); built once at the first step
         self._dims: Optional[tuple] = None
         self._use_sparse_center = False
+        # Algorithm 2's implementation in the compiled round ("pallas" or
+        # "xla", cubic_kernel_plan's for (m, d)); set with the channels
+        self.cubic_solver: Optional[str] = None
         self.uplink: Optional[VectorChannel] = None
         self.downlink: Optional[VectorChannel] = None
         self.grad_uplink: Optional[VectorChannel] = None
@@ -249,6 +252,7 @@ class DistributedCubicNewton:
             )
         self._use_sparse_center = (can_sparse if cfg.sparse_center is None
                                    else bool(cfg.sparse_center))
+        self.cubic_solver = cubic_kernel_plan(m, d)
         if self._dims is not None:
             self._rebuild_jit()   # stale trace would bake the old channels in
         self._dims = (d, m)
@@ -302,13 +306,17 @@ class DistributedCubicNewton:
         return float(loss), float(gn)
 
     # ------------------------------------------------------------------
-    def _worker_update(self, w, X, y, global_g):
-        """One worker: local g, H; solve the cubic sub-problem (Eq. 2).
-        Returns ``(s, iterations of Algorithm 2)``."""
+    def _worker_updates(self, w, X, y, global_g):
+        """Every worker's local g and H, then the cubic sub-problem (Eq. 2)
+        for all of them in one batched solve.  Returns ``(s (m, d),
+        iterations of Algorithm 2 (m,))``."""
         cfg = self.config
-        g = self._grad_fn(w, X, y) if global_g is None else global_g
-        H = self._hess_fn(w, X, y)
-        return solve_cubic_gd_counted(
+        H = jax.vmap(self._hess_fn, in_axes=(None, 0, 0))(w, X, y)
+        if global_g is None:
+            g = jax.vmap(self._grad_fn, in_axes=(None, 0, 0))(w, X, y)
+        else:
+            g = jnp.broadcast_to(global_g, (X.shape[0], w.shape[0]))
+        return solve_cubic_gd_batched(
             g,
             H,
             M=cfg.M,
@@ -339,9 +347,7 @@ class DistributedCubicNewton:
             )
             global_g, _ = self.aggregator(per_g)
 
-        s, cubic_iters = jax.vmap(
-            lambda Xi, yi: self._worker_update(w, Xi, yi, global_g)
-        )(X, y_used)
+        s, cubic_iters = self._worker_updates(w, X, y_used, global_g)
 
         # Uplink: honest workers δ-compress s_i (EF/EF21 memory carries the
         # residual across rounds); the channel's Byzantine hook corrupts the
@@ -404,8 +410,8 @@ class DistributedCubicNewton:
             cfg.eta * v_new, state["downlink"], key=k_down
         )
         w_new = w + delta
-        # the vmapped while_loop runs until its slowest worker is done:
-        # the iterations the device executed this round
+        # Algorithm 2 runs until its slowest worker is done: the
+        # iterations the device executed this round
         info = {
             "update_norms": update_norms, "keep": keep,
             "uplink_delta": uplink_delta,
@@ -611,13 +617,14 @@ class DistributedCubicNewton:
         exact integer uplink/downlink wire totals from the ledger plus the
         per-step cumulative total (the bits-to-ε curve's x axis), the
         per-round measured δ̂, Algorithm 2's iterations a round
-        (``cubic_iters``: the maximum over workers, what the vmapped loop
+        (``cubic_iters``: the maximum over workers, what the batched solve
         runs on the device), and the adaptive-k trajectory (``None``
         entries on non-adaptive wires) — so sweep stores can pivot on
         them.
 
         The rounds run as device stretches: one ``newton.stretch`` span a
-        stretch (``max_rounds``, its round bound), one dispatch and one
+        stretch (``max_rounds``, its round bound; ``solver``, Algorithm 2's
+        implementation, :attr:`cubic_solver`), one dispatch and one
         pull, up to :data:`STRETCH_ROUNDS` rounds with the ε test, the
         pooled evaluation and a tracing ``eval_fn`` inside.  Where the host
         acts between rounds — telemetry on (round records, suspicion), an
@@ -670,7 +677,8 @@ class DistributedCubicNewton:
                          else min(STRETCH_ROUNDS, n_steps - done))
                 k_live = self._uplink_k()   # the k this stretch transmits at
                 bps = self.bits_per_step()  # adaptive k moves it
-                with tel.span("newton.stretch", max_rounds=bound), \
+                with tel.span("newton.stretch", max_rounds=bound,
+                              solver=self.cubic_solver), \
                         compile_scope("newton.step"):
                     w, v, state, key, record, info = self._stretch(
                         w, v, state, X, y, full_data, data, key,

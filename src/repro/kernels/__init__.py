@@ -2,8 +2,9 @@
 
 * :mod:`flash_attention` — online-softmax attention, causal + sliding window
   (the prefill/train hot loop of every attention arch).
-* :mod:`cubic_step` — fused Algorithm-2 inner iteration for the paper's
-  explicit-Hessian regime (the solver hot loop of the reproduction).
+* :mod:`cubic_solve` — Algorithm 2's whole loop for all m workers in one
+  launch, the explicit worker Hessians held in VMEM (the solver hot loop
+  of the paper runtime's round).
 * :mod:`topk_compress` — fused top-k compression payload, the wire
   hot-spot of repro.compression: a single-tile launch (threshold
   bisection + MXU pack) for d ≤ 1408 and a sharded grid-over-blocks
@@ -33,8 +34,7 @@ from .ops import (
     aggregate_sparse_scatter,
     attention_bshd,
     coordinate_median_fused,
-    cubic_solve_fused,
-    cubic_step,
+    cubic_solve_stacked,
     flash_attention,
     kernel_plan,
     krum_scores_fused,
@@ -61,8 +61,7 @@ __all__ = [
     "aggregate_sparse_scatter",
     "attention_bshd",
     "coordinate_median_fused",
-    "cubic_solve_fused",
-    "cubic_step",
+    "cubic_solve_stacked",
     "flash_attention",
     "kernel_plan",
     "krum_scores_fused",

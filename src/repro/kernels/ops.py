@@ -9,7 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .cubic_step import cubic_solve_fused, cubic_step
+from .cubic_solve import cubic_solve_stacked
 from .flash_attention import flash_attention
 from .rmsnorm import rmsnorm
 from .robust_agg import (
@@ -76,8 +76,7 @@ __all__ = [
     "aggregate_sparse_scatter",
     "attention_bshd",
     "coordinate_median_fused",
-    "cubic_solve_fused",
-    "cubic_step",
+    "cubic_solve_stacked",
     "flash_attention",
     "kernel_plan",
     "krum_scores_fused",

@@ -29,15 +29,6 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0):
     return out.astype(q.dtype)
 
 
-def cubic_step_ref(s, g, H, *, M, gamma, lr):
-    """One Algorithm-2 inner iteration (explicit Hessian, the paper's d≤300
-    regime):  G = g + γHs + (Mγ²/2)‖s‖s;  s ← s − ξG."""
-    s32, g32, H32 = s.astype(jnp.float32), g.astype(jnp.float32), H.astype(jnp.float32)
-    sn = jnp.sqrt(jnp.sum(s32 * s32))
-    G = g32 + gamma * (H32 @ s32) + 0.5 * M * gamma**2 * sn * s32
-    return (s32 - lr * G).astype(s.dtype)
-
-
 def topk_compress_ref(x, k):
     """Packed top-|x| payload in index-ascending order (the wire format of
     repro.compression.TopK): values (k,), indices (k,) int32."""

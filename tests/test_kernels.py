@@ -16,7 +16,6 @@ from repro.kernels import (
     aggregate_sparse_scatter,
     attention_bshd,
     coordinate_median_fused,
-    cubic_step,
     flash_attention,
     kernel_plan,
     krum_scores_fused,
@@ -28,9 +27,7 @@ from repro.kernels import (
     topk_decompress,
     trimmed_mean_fused,
 )
-from repro.kernels.cubic_step import cubic_solve_fused
 from repro.kernels.ref import (
-    cubic_step_ref,
     flash_attention_ref,
     krum_scores_ref,
     rmsnorm_ref,
@@ -38,7 +35,6 @@ from repro.kernels.ref import (
     topk_compress_ref,
     topk_compress_sharded_ref,
 )
-from repro.core import solve_cubic_exact
 from repro.core.aggregation import (
     coordinate_median,
     krum_select,
@@ -95,29 +91,6 @@ def test_attention_bshd_gqa(rng):
 
     r = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(o, r, atol=2e-6)
-
-
-@pytest.mark.parametrize("d", [64, 123, 300])
-@pytest.mark.parametrize("dtype", [jnp.float32])
-def test_cubic_step_sweep(d, dtype, rng):
-    k1, k2, k3 = jax.random.split(rng, 3)
-    A = jax.random.normal(k1, (d, d), dtype)
-    H = (A + A.T) / 2
-    g = jax.random.normal(k2, (d,), dtype)
-    s = jax.random.normal(k3, (d,), dtype)
-    o = cubic_step(s, g, H, M=10.0, gamma=1.0, lr=1e-2)
-    r = cubic_step_ref(s, g, H, M=10.0, gamma=1.0, lr=1e-2)
-    np.testing.assert_allclose(o, r, atol=1e-5)
-
-
-def test_cubic_solve_fused_matches_exact(rng):
-    d = 64
-    A = jax.random.normal(rng, (d, d))
-    H = (A + A.T) / 2
-    g = jax.random.normal(jax.random.fold_in(rng, 1), (d,))
-    s = cubic_solve_fused(g, H, n_iters=4000)
-    s_ex = solve_cubic_exact(g, H)
-    np.testing.assert_allclose(s, s_ex, atol=1e-3)
 
 
 @pytest.mark.parametrize("d", [64, 123, 300, 512])

@@ -19,6 +19,7 @@ import pytest
 
 from repro.kernels import (
     aggregate_sparse_gridded,
+    cubic_solve_stacked,
     krum_scores_fused,
     sort_workers_fused,
     topk_compress_sharded,
@@ -74,6 +75,15 @@ CASES = {
     "krum_fused_m20_d300": (
         functools.partial(krum_scores_fused, n_byz=4, interpret=False),
         [((20, 300), jnp.float32)]),
+    # Algorithm 2's whole loop for the w8a and a9a jobs' 20 workers
+    "cubic_solve_m20_d300": (
+        functools.partial(cubic_solve_stacked, interpret=False),
+        [((20, 300), jnp.float32), ((20, 300, 300), jnp.float32),
+         ((20,), jnp.float32)]),
+    "cubic_solve_m20_d123": (
+        functools.partial(cubic_solve_stacked, interpret=False),
+        [((20, 123), jnp.float32), ((20, 123, 123), jnp.float32),
+         ((20,), jnp.float32)]),
     "row_sort_m20_d300": (
         functools.partial(sort_workers_fused, interpret=False),
         [((20, 300), jnp.float32)]),
